@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import accounting
+from repro.launch import runtime
 from repro.data import DataConfig, make_pipeline
 from repro.models import transformer as tf
 from repro.optim import AdamWConfig
@@ -23,7 +24,8 @@ def main():
     params = tf.init_lm(jax.random.PRNGKey(0), cfg, dtype=jnp.float32).params
 
     acct = accounting.CarbonAccountant(accounting.AccountantConfig(
-        device="tpu_v5e", n_devices=jax.device_count(), grid_mix="CA"))
+        device=runtime.accountant_device(), n_devices=jax.device_count(),
+        grid_mix="CA"))
     trainer = Trainer(
         loss_fn=lambda p, b: tf.loss_fn(p, cfg, b),
         params=params,

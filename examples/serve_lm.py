@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import base as cfgbase
 from repro.core import accounting, grid
+from repro.launch import runtime
 from repro.models import transformer as tf
 from repro.serve import ServeConfig, ServeEngine
 
@@ -38,7 +39,7 @@ def main():
     params = tf.init_lm(jax.random.PRNGKey(0), cfg, dtype=jnp.float32).params
 
     acct = accounting.CarbonAccountant(accounting.AccountantConfig(
-        device="tpu_v5e", n_devices=jax.device_count(),
+        device=runtime.accountant_device(), n_devices=jax.device_count(),
         grid_mix=args.grid_mix))
     eng = ServeEngine(params, cfg,
                       ServeConfig(max_slots=args.slots, max_len=256,

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import accounting
+from repro.launch import runtime
 from repro.data import DataConfig, make_pipeline
 from repro.models import transformer as tf
 from repro.optim import AdamWConfig
@@ -61,7 +62,7 @@ def main():
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="e2e_ckpt_")
     hb_dir = tempfile.mkdtemp(prefix="e2e_hb_")
     acct = accounting.CarbonAccountant(accounting.AccountantConfig(
-        device="tpu_v5e", n_devices=jax.device_count(),
+        device=runtime.accountant_device(), n_devices=jax.device_count(),
         grid_mix=args.grid_mix))
     trainer = Trainer(
         loss_fn=lambda p, b: tf.loss_fn(p, cfg, b),

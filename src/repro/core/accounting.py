@@ -25,7 +25,7 @@ SECONDS_PER_YEAR = 365.0 * 86400.0
 
 @dataclasses.dataclass
 class AccountantConfig:
-    device: str = "tpu_v5e"
+    device: str                 # key into hw.DEVICES
     n_devices: int = 1
     grid_mix: str = "NY"
     # Embodied energy per device (J). None -> auto from the LCA layer.
@@ -42,7 +42,7 @@ class CarbonAccountant:
         self._spec = hw.DEVICES[config.device]
         if config.embodied_j_per_device is not None:
             self._embodied_j_dev = config.embodied_j_per_device
-        elif config.device == "tpu_v5e":
+        elif self._spec is hw.TPU_V5E:
             self._embodied_j_dev = lca.tpu_package_embodied_mj() * 1e6
         else:
             self._embodied_j_dev = lca.embodied_energy_mj(self._spec) * 1e6

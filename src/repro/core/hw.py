@@ -133,6 +133,25 @@ DEVICES: Dict[str, DeviceSpec] = {
     d.name: d for d in (RM_PIM, DDR3_PIM, JETSON_NX, VERSAL_VM1802, TPU_V5E)
 }
 
+# Accelerators as JAX reports them (``jax.devices()[0].device_kind``) ->
+# the spec whose peaks and power the accountant bills a run against.
+DEVICE_KINDS: Dict[str, DeviceSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def spec_for_kind(device_kind: str) -> DeviceSpec:
+    """The DeviceSpec of an attached accelerator. A kind missing from
+    :data:`DEVICE_KINDS` is an error, never a default: billing one chip at
+    another's peaks and power would misstate every J/token."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no DeviceSpec for device_kind {device_kind!r}; add it to "
+            f"repro.core.hw.DEVICE_KINDS (known: "
+            f"{sorted(DEVICE_KINDS)})") from None
+
 
 # ----------------------------------------------------------------------------
 # Table 3 measured operational characterization.

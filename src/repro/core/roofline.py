@@ -265,8 +265,7 @@ class RooflineTerms:
 def from_compiled(compiled, n_devices: int, label: str = "",
                   hlo_text: Optional[str] = None) -> RooflineTerms:
     """Build RooflineTerms from a jax ``Compiled`` object."""
-    from repro.parallel.compat import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     text = hlo_text if hlo_text is not None else compiled.as_text()
     colls = parse_collectives(text)
     ma = None

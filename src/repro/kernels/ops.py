@@ -8,7 +8,10 @@ mode (bit-accurate kernel-body semantics, Python-speed) — use
 
 from __future__ import annotations
 
+import base64
+import collections
 import functools
+import re
 from typing import Optional
 
 import jax
@@ -24,6 +27,25 @@ from repro.quant.ternary import TernaryWeight
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+_MOSAIC_BODY = re.compile(
+    r'custom_call_target="tpu_custom_call".*?"body":"([^"]+)"')
+# the first such symbol in a serialized Mosaic body is the kernel function;
+# later ones are source locations
+_KERNEL_SYMBOL = re.compile(rb"[A-Za-z0-9_]+_kernel")
+
+
+def compiled_kernels(hlo_text: str) -> collections.Counter:
+    """Pallas kernels that a compiled TPU program (``compiled.as_text()``)
+    runs as Mosaic custom calls, counted by kernel function name. Interpret
+    mode lowers a kernel body to plain HLO ops, so a kernel missing here
+    does not run compiled in that program."""
+    names: collections.Counter = collections.Counter()
+    for body in _MOSAIC_BODY.findall(hlo_text):
+        m = _KERNEL_SYMBOL.search(base64.b64decode(body))
+        names[m.group(0).decode() if m else "?"] += 1
+    return names
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0.0) -> jnp.ndarray:

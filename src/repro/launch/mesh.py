@@ -3,16 +3,20 @@ touches jax device state)."""
 
 from __future__ import annotations
 
-from repro.parallel import compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh helper (tests, elastic replanning)."""
-    return compat.make_mesh(shape, axes)
+    """Arbitrary mesh helper (tests, elastic replanning), with Auto axis
+    types: the sharding rules leave placement to the partitioner."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -1,13 +1,16 @@
 """Serving launcher: batched request serving with carbon accounting.
 
-CPU-runnable with --smoke (reduced configs); production decode shapes are
-proven via launch.dryrun (decode_32k / long_500k cells).
+``--smoke`` serves the arch's reduced config, on the CPU (kernels in
+interpret mode) or on a TPU. The published widths run on one TPU v5e
+through ``python chip_smoke.py``, which builds its engines with
+:func:`build_engine` and cuts the depth to what one chip holds.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,9 +18,26 @@ import numpy as np
 
 from repro.configs import base as cfgbase
 from repro.core import accounting
+from repro.launch import runtime
 from repro.models import transformer as tf_lib
 from repro.serve import (FAULT_KINDS, FaultPlan, ProcessKilled, Scheduler,
                          SchedulerConfig, ServeConfig, ServeEngine)
+
+
+def build_engine(params, cfg: tf_lib.LMConfig, scfg: ServeConfig, *,
+                 policy: str = "fifo", grid_mix: str = "NY",
+                 accountant: Optional[accounting.CarbonAccountant] = None
+                 ) -> ServeEngine:
+    """The serving engine as this launcher and ``chip_smoke.py`` build it.
+    Without an ``accountant`` a new one bills the device that runs the
+    engine (``runtime.accountant_device``); pass the old one to keep a
+    single ledger across a warm restart."""
+    if accountant is None:
+        accountant = accounting.CarbonAccountant(accounting.AccountantConfig(
+            device=runtime.accountant_device(),
+            n_devices=jax.device_count(), grid_mix=grid_mix))
+    return ServeEngine(params, cfg, scfg, accountant=accountant,
+                       scheduler=Scheduler(SchedulerConfig(policy=policy)))
 
 
 def validate_args(ap: argparse.ArgumentParser,
@@ -163,17 +183,17 @@ def main() -> None:
     validate_args(ap, args)
 
     if not args.smoke:
-        raise SystemExit("full-scale serving needs a TPU fleet; use --smoke "
-                         "or `python -m repro.launch.dryrun` for the decode "
-                         "cells.")
+        raise SystemExit("this launcher serves the reduced config only; pass "
+                         "--smoke. The published widths run on one TPU v5e "
+                         "through `python chip_smoke.py` (depth cut to fit "
+                         "the chip).")
+    runtime.enable_compile_cache()
     arch = cfgbase.get(args.arch)
     if arch.kind != "lm":
         raise SystemExit(f"serve launcher supports LM archs; {args.arch} is "
                          f"{arch.kind}")
     cfg = arch.make_smoke()
     params = tf_lib.init_lm(jax.random.PRNGKey(0), cfg, dtype=jnp.float32).params
-    acct = accounting.CarbonAccountant(accounting.AccountantConfig(
-        device="tpu_v5e", n_devices=jax.device_count(), grid_mix=args.grid_mix))
     scfg = ServeConfig(max_slots=args.slots, max_len=256,
                        temperature=args.temperature,
                        quant=args.quant, paged=args.paged,
@@ -193,12 +213,9 @@ def main() -> None:
                        checkpoint_dir=args.checkpoint_dir,
                        checkpoint_interval=args.checkpoint_interval)
 
-    def build() -> ServeEngine:
-        return ServeEngine(params, cfg, scfg, accountant=acct,
-                           scheduler=Scheduler(
-                               SchedulerConfig(policy=args.policy)))
-
-    eng = build()
+    eng = build_engine(params, cfg, scfg, policy=args.policy,
+                       grid_mix=args.grid_mix)
+    acct = eng.accountant
     done = []
     if args.resume:
         done.extend(eng.restore())
@@ -217,7 +234,8 @@ def main() -> None:
             # dead — restart purely from disk and keep serving
             print(f"engine killed ({e}); warm-restarting from "
                   f"{args.checkpoint_dir}")
-            eng = build()
+            eng = build_engine(params, cfg, scfg, policy=args.policy,
+                               accountant=acct)
             done.extend(eng.restore())
     # restore delivery is at-least-once: dedupe by uid, keep stream order
     done = sorted({r.uid: r for r in done}.values(), key=lambda r: r.uid)

@@ -1,10 +1,10 @@
-"""Training launcher: full FT loop on a (possibly multi-pod) mesh.
+"""Training launcher: the host-loop FT Trainer or the fused TrainEngine.
 
-CPU-friendly path: ``--smoke`` runs the arch's reduced config end-to-end
-(real steps, real checkpoints, real accounting). The production path takes
-``--mesh single|multi`` and shards params/optimizer/data exactly as the
-dry-run proves out; on this CPU container the full configs are exercised via
-``launch.dryrun`` instead.
+``--smoke`` runs the arch's reduced config end to end (real steps, real
+checkpoints, real accounting), on the CPU (kernels in interpret mode) or on
+a TPU. The fused tick at published widths runs on one TPU v5e through
+``python chip_smoke.py``, which builds its engine with :func:`build_engine`
+and cuts the depth to what one chip holds.
 
 Example (the (b) end-to-end driver uses this):
   PYTHONPATH=src python -m repro.launch.train --arch gemma3-27b --smoke \
@@ -26,6 +26,7 @@ import numpy as np
 from repro.configs import base as cfgbase
 from repro.core import accounting
 from repro.data import DataConfig, make_pipeline
+from repro.launch import runtime
 from repro.models import encdec as encdec_lib
 from repro.models import transformer as tf_lib
 from repro.optim import AdamWConfig
@@ -69,8 +70,7 @@ def build_smoke_trainer(arch_id: str, *, steps: int, ckpt_dir: Optional[str],
     pipeline = make_pipeline(DataConfig(
         vocab=vocab, seq_len=seq_len, global_batch=global_batch,
         seed=seed, source="markov"))
-    acct = accounting.CarbonAccountant(accounting.AccountantConfig(
-        device="tpu_v5e", n_devices=jax.device_count(), grid_mix=grid_mix))
+    acct = _accountant(grid_mix)
     hb = (HeartbeatWriter(heartbeat_dir, host_id="host0")
           if heartbeat_dir else None)
     trainer = Trainer(
@@ -82,6 +82,26 @@ def build_smoke_trainer(arch_id: str, *, steps: int, ckpt_dir: Optional[str],
         ckpt_cfg=(CheckpointConfig(directory=ckpt_dir) if ckpt_dir else None),
         accountant=acct, heartbeat=hb)
     return trainer
+
+
+def _accountant(grid_mix: str) -> accounting.CarbonAccountant:
+    return accounting.CarbonAccountant(accounting.AccountantConfig(
+        device=runtime.accountant_device(), n_devices=jax.device_count(),
+        grid_mix=grid_mix))
+
+
+def build_engine(params, cfg, pipeline, *, steps: int,
+                 steps_per_tick: int = 8, lr: float = 3e-3,
+                 grid_mix: str = "NY") -> TrainEngine:
+    """The fused TrainEngine as this launcher and ``chip_smoke.py`` build
+    it: AdamW on a warmup-cosine schedule over ``steps``, billed to the
+    device that runs it (``runtime.accountant_device``)."""
+    return TrainEngine.for_lm(
+        params, cfg,
+        opt_cfg=AdamWConfig(lr=warmup_cosine(lr, max(steps // 10, 1), steps)),
+        pipeline=pipeline,
+        engine_cfg=TrainEngineConfig(steps_per_tick=steps_per_tick),
+        accountant=_accountant(grid_mix))
 
 
 def build_smoke_engine(arch_id: str, *, steps: int, grid_mix: str = "NY",
@@ -102,14 +122,9 @@ def build_smoke_engine(arch_id: str, *, steps: int, grid_mix: str = "NY",
     pipeline = make_pipeline(DataConfig(
         vocab=cfg.vocab, seq_len=seq_len, global_batch=global_batch,
         seed=seed, source="markov"))
-    acct = accounting.CarbonAccountant(accounting.AccountantConfig(
-        device="tpu_v5e", n_devices=jax.device_count(), grid_mix=grid_mix))
-    return TrainEngine.for_lm(
-        params, cfg,
-        opt_cfg=AdamWConfig(lr=warmup_cosine(lr, max(steps // 10, 1), steps)),
-        pipeline=pipeline,
-        engine_cfg=TrainEngineConfig(steps_per_tick=steps_per_tick),
-        accountant=acct)
+    return build_engine(params, cfg, pipeline, steps=steps,
+                        steps_per_tick=steps_per_tick, lr=lr,
+                        grid_mix=grid_mix)
 
 
 def main() -> None:
@@ -132,9 +147,10 @@ def main() -> None:
 
     if not args.smoke:
         raise SystemExit(
-            "full-scale training needs a TPU fleet; on this container use "
-            "`python -m repro.launch.dryrun` (the compile-time proof) or "
-            "--smoke (the runnable reduced config).")
+            "this launcher trains the reduced config only; pass --smoke. "
+            "The fused tick at published widths runs on one TPU v5e through "
+            "`python chip_smoke.py` (depth cut to fit the chip).")
+    runtime.enable_compile_cache()
 
     if args.engine == "fused":
         eng = build_smoke_engine(args.arch, steps=args.steps,

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.models import common
 from repro.models.common import Axed, group, leaf
-from repro.parallel.ctx import constrain
+from repro.parallel.ctx import constrain, sharding_active
 
 def wl(w, dtype):
     """Weight loader: dequantize int8-served weights at use (fused into the
@@ -420,8 +420,12 @@ def attention(params, cfg: AttnConfig, x: jnp.ndarray,
     q, k, v = _project_qkv(params, cfg, x, positions)
     pos1d = positions[..., 0] if positions.ndim == 3 else positions
     w = cfg.window if window is None else window
+    # sequence-parallel attention only changes anything under an active
+    # sharding context (its constraints are no-ops without one), so only
+    # there does it keep the unpartitionable kernel off
+    seq_sharded = cfg.sp and sharding_active()
     if (cfg.flash_vjp and arange_positions and cfg.causal
-            and isinstance(w, int) and not cfg.sp
+            and isinstance(w, int) and not seq_sharded
             and cfg.pos_emb != "mrope"):
         # training fast path: block-index masking is exact because the
         # caller vouched positions == arange (packed/custom-position

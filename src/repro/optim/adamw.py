@@ -78,7 +78,10 @@ def init_opt_state(params: PyTree, cfg: AdamWConfig) -> Dict[str, PyTree]:
                                 "step": jnp.zeros((), jnp.int32)}
     if cfg.use_master and any(p.dtype != jnp.float32
                               for p in jax.tree.leaves(params)):
-        state["master"] = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+        # a copy even of leaves that are already fp32 (norm scales): a
+        # master aliasing its param would be donated twice by a train tick
+        state["master"] = jax.tree.map(
+            lambda p: jnp.array(p, jnp.float32, copy=True), params)
     return state
 
 

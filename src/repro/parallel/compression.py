@@ -19,8 +19,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.parallel import compat
-
 PyTree = Any
 
 
@@ -40,7 +38,7 @@ def ring_allreduce_int8(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 
     Call inside shard_map. Wire bytes: ~2 * size * (n-1)/n * 1B vs 4B fp32.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -67,7 +65,8 @@ def ring_allreduce_int8(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 
     q0, s0 = _quantize(jax.lax.dynamic_index_in_dim(chunks, idx % n, 0,
                                                     keepdims=False))
-    acc0 = compat.pvary(jnp.zeros(chunks.shape[1], jnp.float32), (axis_name,))
+    acc0 = jax.lax.pcast(jnp.zeros(chunks.shape[1], jnp.float32), (axis_name,),
+                         to="varying")
     acc, q_fin, s_fin = jax.lax.fori_loop(0, n - 1, rs_body, (acc0, q0, s0))
     # rank r now owns the reduced chunk (r + 1) % n  (as q_fin/s_fin)
     own_id = (idx + 1) % n

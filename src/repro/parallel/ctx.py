@@ -36,6 +36,11 @@ def activation_sharding(mesh: Mesh, rules=None):
         _state.ctx = prev
 
 
+def sharding_active() -> bool:
+    """Whether an :func:`activation_sharding` context is installed."""
+    return _current() is not None
+
+
 def constrain(x, *axes: Optional[str]):
     """Constrain ``x``'s sharding by logical axis names (no-op w/o context)."""
     ctx = _current()

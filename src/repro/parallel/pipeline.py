@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel import compat
-
 PyTree = Any
 
 
@@ -38,7 +36,7 @@ def pipeline_apply(stage_fn: Callable[[PyTree, jnp.ndarray], jnp.ndarray],
     Returns (M, mb, ...) final-stage outputs (identical on every rank).
     """
     p = jax.lax.axis_index(axis_name)
-    n_stage = compat.axis_size(axis_name)
+    n_stage = jax.lax.axis_size(axis_name)
     m = microbatches.shape[0]
     ticks = m + n_stage - 1
     state0 = jnp.zeros_like(microbatches[0])
@@ -75,8 +73,8 @@ def make_pipelined_fn(stage_fn: Callable, mesh: Mesh, n_micro: int,
     """
     n_stage = mesh.shape[axis_name]
 
-    @partial(compat.shard_map, mesh=mesh,
-             in_specs=(P(axis_name), P()), out_specs=P())
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(axis_name), P()),
+             out_specs=P(), check_vma=False)
     def _run(stacked_params, x):
         local_params = jax.tree.map(lambda a: a[0], stacked_params)
         b = x.shape[0]

@@ -83,7 +83,7 @@ class TestReshard:
         mgr = CheckpointManager(CheckpointConfig(str(tmp_path), async_save=False))
         tree = {"w": jnp.arange(16.0).reshape(8, 2)}
         mgr.save(1, tree)
-        from repro.parallel.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((1,), ("data",))
         shardings = {"w": NamedSharding(mesh, P("data", None))}
         step, restored, _ = mgr.restore(target=tree, shardings=shardings)

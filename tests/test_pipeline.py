@@ -7,7 +7,7 @@ def test_pipeline_matches_sequential():
     out = run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.parallel import pipeline as pp
-from repro.parallel.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("pipe",))
 key = jax.random.PRNGKey(0)
 n_stage, d, batch, micro = 4, 16, 8, 4

@@ -151,6 +151,7 @@ class TestAccountantTrainLedger:
         assert rep["tokens"] == 2 * BATCH * SEQ
 
     def test_no_train_block_without_training(self):
-        acct = accounting.CarbonAccountant(accounting.AccountantConfig())
+        acct = accounting.CarbonAccountant(accounting.AccountantConfig(
+            device="tpu_v5e"))
         assert acct.train_report() is None
         assert "train" not in acct.report()

@@ -101,6 +101,20 @@ class TestStepParity:
         assert eng.tick_trace_count == 2
         assert eng.host_readbacks == 3
 
+    def test_bf16_params_with_fp32_master_donate_cleanly(self):
+        """bf16 weights keep fp32 norm scales; their fp32 master must be a
+        copy, or the donated tick would receive one buffer twice."""
+        cfg = _cfg()
+        params = tf_lib.init_lm(jax.random.PRNGKey(0), cfg,
+                                dtype=jnp.bfloat16).params
+        assert {p.dtype for p in jax.tree.leaves(params)} == {
+            jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)}
+        eng = TrainEngine.for_lm(
+            params, cfg, opt_cfg=AdamWConfig(lr=1e-3), pipeline=_pipe(),
+            engine_cfg=TrainEngineConfig(steps_per_tick=2))
+        eng.run(4)
+        assert np.isfinite(eng.last_metrics.loss)
+
 
 class TestLearning:
     def test_loss_decreases_over_20_steps(self):

@@ -7,8 +7,7 @@ Usage: python tools/check_skips.py <pytest-output.txt> <baseline-file>
 The baseline file holds one integer — the maximum allowed skip count in the
 full-dependency CI environment (0: with hypothesis installed, every
 property test runs; a rising skip count means a dependency or marker
-silently regressed). Local bare-environment runs legitimately skip the
-hypothesis-backed tests via the conftest shim; this gate only runs in CI.
+silently regressed).
 """
 
 from __future__ import annotations
