@@ -1,7 +1,7 @@
 # Tier-1 verify — exactly as ROADMAP.md specifies.
 PY ?= python
 
-.PHONY: verify lint bench bench-serve bench-train
+.PHONY: verify lint bench bench-serve
 
 verify:
 	PYTHONPATH=src $(PY) -m pytest -x -q
@@ -33,8 +33,3 @@ bench-serve:
 	PYTHONPATH=src $(PY) benchmarks/serve_bench.py --paged --nbest 4
 	PYTHONPATH=src $(PY) benchmarks/serve_bench.py --paged --long-context
 	PYTHONPATH=src $(PY) benchmarks/serve_bench.py --chaos
-
-# training fast path (DESIGN.md §13): fused TrainEngine tick vs the
-# host-loop autodiff-through-reference Trainer -> BENCH_train.json
-bench-train:
-	PYTHONPATH=src $(PY) benchmarks/train_bench.py

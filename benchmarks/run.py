@@ -5,13 +5,13 @@ import sys
 def main() -> None:
     from benchmarks import (fig2_sustainability, kernel_bench, roofline_table,
                             serve_bench, table1_gridmix, table2_embodied,
-                            table3_efficiency, train_bench)
+                            table3_efficiency)
     from benchmarks.bench_util import emit
 
     rows = []
     for mod in (table1_gridmix, table2_embodied, table3_efficiency,
                 fig2_sustainability, kernel_bench, roofline_table,
-                serve_bench, train_bench):
+                serve_bench):
         try:
             rows.extend(mod.run())
         except Exception as e:  # a missing artifact must not hide the rest

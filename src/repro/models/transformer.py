@@ -260,26 +260,30 @@ def _apply_block(params, shared_params, cfg: LMConfig, spec: BlockSpec,
     if spec.kind == "attn":
         p = shared_params if spec.shared_attn else params
         acfg = cfg.attn_cfg(spec.window)
-        h = layers.rms_norm(p["norm_attn"], x)
-        x = x + layers.attention(p["attn"], acfg, h, positions,
-                                 arange_positions=arange_pos)
+        with jax.named_scope("attn"):
+            h = layers.rms_norm(p["norm_attn"], x)
+            x = x + layers.attention(p["attn"], acfg, h, positions,
+                                     arange_positions=arange_pos)
         if spec.shared_attn:
-            h = layers.rms_norm(p["norm_ffn"], x)
-            x = x + layers.mlp(p["mlp"], h, cfg.act,
-                               int8_kernel=cfg.use_int8_matmul)
+            with jax.named_scope("mlp"):
+                h = layers.rms_norm(p["norm_ffn"], x)
+                x = x + layers.mlp(p["mlp"], h, cfg.act,
+                                   int8_kernel=cfg.use_int8_matmul)
             return x, aux
     elif spec.kind == "ssd":
-        h = layers.rms_norm(params["norm_ssd"], x)
-        x = x + ssd_lib.ssd_block(params["ssd"], cfg.ssd_cfg, h)
+        with jax.named_scope("ssd"):
+            h = layers.rms_norm(params["norm_ssd"], x)
+            x = x + ssd_lib.ssd_block(params["ssd"], cfg.ssd_cfg, h)
     if spec.kind == "attn" and spec.has_ffn and not spec.shared_attn:
-        h = layers.rms_norm(params["norm_ffn"], x)
-        if spec.moe:
-            y, aux = moe_lib.moe_capacity(params["moe"], cfg.moe_cfg, h,
-                                          cfg.moe_group_size)
-            x = x + y
-        else:
-            x = x + layers.mlp(params["mlp"], h, cfg.act,
-                               int8_kernel=cfg.use_int8_matmul)
+        with jax.named_scope("mlp"):
+            h = layers.rms_norm(params["norm_ffn"], x)
+            if spec.moe:
+                y, aux = moe_lib.moe_capacity(params["moe"], cfg.moe_cfg, h,
+                                              cfg.moe_group_size)
+                x = x + y
+            else:
+                x = x + layers.mlp(params["mlp"], h, cfg.act,
+                                   int8_kernel=cfg.use_int8_matmul)
     if cfg.sp_residual:
         x = constrain(x, "batch", "seq_tp", None)
     return x, aux
@@ -314,11 +318,12 @@ def forward(params, cfg: LMConfig, tokens: jnp.ndarray,
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-sequence forward. tokens (B,S) -> (logits (B,S,V) fp32, aux)."""
     b, s = tokens.shape
-    x = layers.embed(params["embed"], tokens)
-    if vision_embeds is not None and cfg.vision_tokens > 0:
-        x = jax.lax.dynamic_update_slice(
-            x, vision_embeds.astype(x.dtype), (0, 0, 0))
-    x = constrain(x, "batch", "seq", None)
+    with jax.named_scope("embed"):
+        x = layers.embed(params["embed"], tokens)
+        if vision_embeds is not None and cfg.vision_tokens > 0:
+            x = jax.lax.dynamic_update_slice(
+                x, vision_embeds.astype(x.dtype), (0, 0, 0))
+        x = constrain(x, "batch", "seq", None)
     arange_pos = positions is None
     if positions is None:
         pos1d = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
@@ -346,14 +351,15 @@ def forward(params, cfg: LMConfig, tokens: jnp.ndarray,
         x, a = _apply_block(p, shared, cfg, spec, x, positions,
                             arange_pos=arange_pos)
         aux = aux + a
-    x = layers.rms_norm(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(params["embed"], x)
-    else:
-        logits = layers.apply_unembed(params["unembed"], x)
-    if cfg.logit_softcap > 0:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    logits = constrain(logits, "batch", "seq", "vocab")
+    with jax.named_scope("head"):
+        x = layers.rms_norm(params["final_norm"], x)
+        if cfg.tie_embeddings:
+            logits = layers.unembed(params["embed"], x)
+        else:
+            logits = layers.apply_unembed(params["unembed"], x)
+        if cfg.logit_softcap > 0:
+            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        logits = constrain(logits, "batch", "seq", "vocab")
     return logits, aux
 
 
@@ -363,16 +369,18 @@ def loss_fn(params, cfg: LMConfig, batch: Dict[str, jnp.ndarray]
     logits, aux = forward(params, cfg, batch["tokens"],
                           positions=batch.get("positions"),
                           vision_embeds=batch.get("vision_embeds"))
-    labels = batch["labels"]
-    mask = batch.get("loss_mask", jnp.ones_like(labels, jnp.float32))
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    label_logit = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = (logz - label_logit) * mask
-    denom = jnp.maximum(mask.sum(), 1.0)
-    ce = nll.sum() / denom
-    total = ce + aux
-    if cfg.z_loss > 0:
-        total = total + cfg.z_loss * ((logz * mask) ** 2).sum() / denom
+    with jax.named_scope("loss"):
+        labels = batch["labels"]
+        mask = batch.get("loss_mask", jnp.ones_like(labels, jnp.float32))
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        label_logit = jnp.take_along_axis(logits, labels[..., None],
+                                          axis=-1)[..., 0]
+        nll = (logz - label_logit) * mask
+        denom = jnp.maximum(mask.sum(), 1.0)
+        ce = nll.sum() / denom
+        total = ce + aux
+        if cfg.z_loss > 0:
+            total = total + cfg.z_loss * ((logz * mask) ** 2).sum() / denom
     return total, {"ce": ce, "aux": aux, "tokens": denom}
 
 
@@ -522,28 +530,33 @@ def _decode_block(params, shared_params, cfg: LMConfig, spec: BlockSpec,
                   x, cache, pos):
     if spec.kind == "attn":
         p = shared_params if spec.shared_attn else params
-        h = layers.rms_norm(p["norm_attn"], x)
-        y, cache = _decode_attn(p, cfg, spec, h, cache, pos)
-        x = x + y
+        with jax.named_scope("attn"):
+            h = layers.rms_norm(p["norm_attn"], x)
+            y, cache = _decode_attn(p, cfg, spec, h, cache, pos)
+            x = x + y
         if spec.shared_attn:
-            h = layers.rms_norm(p["norm_ffn"], x)
-            return x + layers.mlp(p["mlp"], h, cfg.act,
-                               int8_kernel=cfg.use_int8_matmul), cache
+            with jax.named_scope("mlp"):
+                h = layers.rms_norm(p["norm_ffn"], x)
+                return x + layers.mlp(p["mlp"], h, cfg.act,
+                                      int8_kernel=cfg.use_int8_matmul), cache
     else:
-        h = layers.rms_norm(params["norm_ssd"], x)
-        y, st = ssd_lib.ssd_block_decode(params["ssd"], cfg.ssd_cfg, h,
-                                         cache["ssd"])
-        x = x + y
+        with jax.named_scope("ssd"):
+            h = layers.rms_norm(params["norm_ssd"], x)
+            y, st = ssd_lib.ssd_block_decode(params["ssd"], cfg.ssd_cfg, h,
+                                             cache["ssd"])
+            x = x + y
         cache = {"ssd": st}
     if spec.kind == "attn" and spec.has_ffn and not spec.shared_attn:
-        h = layers.rms_norm(params["norm_ffn"], x)
-        if spec.moe:
-            y, _ = moe_lib.moe_capacity(params["moe"], cfg.moe_cfg, h,
-                                        group_size=h.shape[0] * h.shape[1])
-            x = x + y
-        else:
-            x = x + layers.mlp(params["mlp"], h, cfg.act,
-                               int8_kernel=cfg.use_int8_matmul)
+        with jax.named_scope("mlp"):
+            h = layers.rms_norm(params["norm_ffn"], x)
+            if spec.moe:
+                y, _ = moe_lib.moe_capacity(
+                    params["moe"], cfg.moe_cfg, h,
+                    group_size=h.shape[0] * h.shape[1])
+                x = x + y
+            else:
+                x = x + layers.mlp(params["mlp"], h, cfg.act,
+                                   int8_kernel=cfg.use_int8_matmul)
     return x, cache
 
 
@@ -589,14 +602,15 @@ def _lm_head(params, cfg: LMConfig, x: jnp.ndarray) -> jnp.ndarray:
     harmless for argmax (tanh is monotonic) but it biased first-token
     *temperature* sampling on softcap archs; unified here (no shipped
     config sets softcap > 0, so no behavior shift today)."""
-    x = layers.rms_norm(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(params["embed"], x)
-    else:
-        logits = layers.apply_unembed(params["unembed"], x)
-    if cfg.logit_softcap > 0:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return logits[..., :cfg.vocab]
+    with jax.named_scope("head"):
+        x = layers.rms_norm(params["final_norm"], x)
+        if cfg.tie_embeddings:
+            logits = layers.unembed(params["embed"], x)
+        else:
+            logits = layers.apply_unembed(params["unembed"], x)
+        if cfg.logit_softcap > 0:
+            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        return logits[..., :cfg.vocab]
 
 
 # -----------------------------------------------------------------------------
@@ -870,22 +884,26 @@ def _paged_decode_block(params, shared_params, cfg: LMConfig,
                         spec: BlockSpec, x, cache, pos, page_table, active,
                         attn=_paged_decode_attn):
     p = shared_params if spec.shared_attn else params
-    h = layers.rms_norm(p["norm_attn"], x)
-    y, cache = attn(p, cfg, spec, h, cache, pos, page_table, active)
-    x = x + y
+    with jax.named_scope("attn"):
+        h = layers.rms_norm(p["norm_attn"], x)
+        y, cache = attn(p, cfg, spec, h, cache, pos, page_table, active)
+        x = x + y
     if spec.shared_attn:
-        h = layers.rms_norm(p["norm_ffn"], x)
-        return x + layers.mlp(p["mlp"], h, cfg.act,
-                              int8_kernel=cfg.use_int8_matmul), cache
+        with jax.named_scope("mlp"):
+            h = layers.rms_norm(p["norm_ffn"], x)
+            return x + layers.mlp(p["mlp"], h, cfg.act,
+                                  int8_kernel=cfg.use_int8_matmul), cache
     if spec.has_ffn:
-        h = layers.rms_norm(params["norm_ffn"], x)
-        if spec.moe:
-            y, _ = moe_lib.moe_capacity(params["moe"], cfg.moe_cfg, h,
-                                        group_size=h.shape[0] * h.shape[1])
-            x = x + y
-        else:
-            x = x + layers.mlp(params["mlp"], h, cfg.act,
-                               int8_kernel=cfg.use_int8_matmul)
+        with jax.named_scope("mlp"):
+            h = layers.rms_norm(params["norm_ffn"], x)
+            if spec.moe:
+                y, _ = moe_lib.moe_capacity(
+                    params["moe"], cfg.moe_cfg, h,
+                    group_size=h.shape[0] * h.shape[1])
+                x = x + y
+            else:
+                x = x + layers.mlp(params["mlp"], h, cfg.act,
+                                   int8_kernel=cfg.use_int8_matmul)
     return x, cache
 
 
@@ -1098,20 +1116,23 @@ def paged_extend(params, cfg: LMConfig, tokens: jnp.ndarray,
 
     def fill_block(p, spec, x, cache):
         pp = shared if spec.shared_attn else p
-        x, cache = fill_attn(pp, spec, x, cache)
+        with jax.named_scope("attn"):
+            x, cache = fill_attn(pp, spec, x, cache)
         if spec.shared_attn:
-            h = layers.rms_norm(pp["norm_ffn"], x)
-            return x + layers.mlp(pp["mlp"], h, cfg.act,
-                                  int8_kernel=cfg.use_int8_matmul), cache
+            with jax.named_scope("mlp"):
+                h = layers.rms_norm(pp["norm_ffn"], x)
+                return x + layers.mlp(pp["mlp"], h, cfg.act,
+                                      int8_kernel=cfg.use_int8_matmul), cache
         if spec.has_ffn:
-            h = layers.rms_norm(p["norm_ffn"], x)
-            if spec.moe:
-                y, _ = moe_lib.moe_capacity(p["moe"], cfg.moe_cfg, h,
-                                            cfg.moe_group_size)
-                x = x + y
-            else:
-                x = x + layers.mlp(p["mlp"], h, cfg.act,
-                                   int8_kernel=cfg.use_int8_matmul)
+            with jax.named_scope("mlp"):
+                h = layers.rms_norm(p["norm_ffn"], x)
+                if spec.moe:
+                    y, _ = moe_lib.moe_capacity(p["moe"], cfg.moe_cfg, h,
+                                                cfg.moe_group_size)
+                    x = x + y
+                else:
+                    x = x + layers.mlp(p["mlp"], h, cfg.act,
+                                       int8_kernel=cfg.use_int8_matmul)
         return x, cache
 
     def body(x, inp):

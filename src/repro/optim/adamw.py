@@ -100,54 +100,59 @@ def clip_by_global_norm(grads: PyTree, max_norm: float) -> Tuple[PyTree, jnp.nda
 def apply_updates(params: PyTree, grads: PyTree, state: Dict[str, PyTree],
                   cfg: AdamWConfig) -> Tuple[PyTree, Dict[str, PyTree],
                                              Dict[str, jnp.ndarray]]:
-    step = state["step"] + 1
-    lr = cfg.lr_at(step)
-    if cfg.grad_clip > 0:
-        grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
-    else:
-        grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        gnorm = global_norm(grads)
-    b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
-    masters = state.get("master", params)
+    """One AdamW step with clipping by the global gradient norm. In a
+    profile every operation of the step, the norm and the clipping
+    included, sits under the scope ``adamw``."""
+    with jax.named_scope("adamw"):
+        step = state["step"] + 1
+        lr = cfg.lr_at(step)
+        if cfg.grad_clip > 0:
+            grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
+        else:
+            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            gnorm = global_norm(grads)
+        b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
+        masters = state.get("master", params)
 
-    is_q = lambda x: isinstance(x, dict) and set(x) == {"q", "s"}
+        is_q = lambda x: isinstance(x, dict) and set(x) == {"q", "s"}
 
-    def upd(p, master, g, m, v):
-        m32 = _load(m, cfg.state_dtype)
-        v32 = _load(v, cfg.state_dtype)
-        m32 = cfg.b1 * m32 + (1 - cfg.b1) * g
-        v32 = cfg.b2 * v32 + (1 - cfg.b2) * jnp.square(g)
-        mhat = m32 / b1c
-        vhat = v32 / b2c
-        base = master.astype(jnp.float32)
-        new = base - lr * (mhat / (jnp.sqrt(vhat) + cfg.eps)
-                           + cfg.weight_decay * base)
-        return new, _store(m32, cfg.state_dtype), _store(v32, cfg.state_dtype)
+        def upd(p, master, g, m, v):
+            m32 = _load(m, cfg.state_dtype)
+            v32 = _load(v, cfg.state_dtype)
+            m32 = cfg.b1 * m32 + (1 - cfg.b1) * g
+            v32 = cfg.b2 * v32 + (1 - cfg.b2) * jnp.square(g)
+            mhat = m32 / b1c
+            vhat = v32 / b2c
+            base = master.astype(jnp.float32)
+            new = base - lr * (mhat / (jnp.sqrt(vhat) + cfg.eps)
+                               + cfg.weight_decay * base)
+            return (new, _store(m32, cfg.state_dtype),
+                    _store(v32, cfg.state_dtype))
 
-    flat_p, treedef = jax.tree.flatten(params)
-    flat_master = jax.tree.leaves(masters)
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(state["m"], is_leaf=is_q)
-    flat_v = jax.tree.leaves(state["v"], is_leaf=is_q)
-    new_p, new_m, new_v = [], [], []
-    for p, ms, g, m, v in zip(flat_p, flat_master, flat_g, flat_m, flat_v):
-        np_, nm, nv = upd(p, ms, g, m, v)
-        new_p.append(np_)
-        new_m.append(nm)
-        new_v.append(nv)
-    new_master_tree = jax.tree.unflatten(treedef, new_p)
-    new_params = jax.tree.map(lambda old, new: new.astype(old.dtype),
-                              params, new_master_tree)
-    new_state: Dict[str, PyTree] = {
-        "m": jax.tree.unflatten(treedef, new_m),
-        "v": jax.tree.unflatten(treedef, new_v),
-        "step": step,
-    }
-    if "master" in state:
-        new_state["master"] = new_master_tree
-    metrics = {"grad_norm": gnorm, "lr": lr}
-    return new_params, new_state, metrics
+        flat_p, treedef = jax.tree.flatten(params)
+        flat_master = jax.tree.leaves(masters)
+        flat_g = jax.tree.leaves(grads)
+        flat_m = jax.tree.leaves(state["m"], is_leaf=is_q)
+        flat_v = jax.tree.leaves(state["v"], is_leaf=is_q)
+        new_p, new_m, new_v = [], [], []
+        for p, ms, g, m, v in zip(flat_p, flat_master, flat_g, flat_m, flat_v):
+            np_, nm, nv = upd(p, ms, g, m, v)
+            new_p.append(np_)
+            new_m.append(nm)
+            new_v.append(nv)
+        new_master_tree = jax.tree.unflatten(treedef, new_p)
+        new_params = jax.tree.map(lambda old, new: new.astype(old.dtype),
+                                  params, new_master_tree)
+        new_state: Dict[str, PyTree] = {
+            "m": jax.tree.unflatten(treedef, new_m),
+            "v": jax.tree.unflatten(treedef, new_v),
+            "step": step,
+        }
+        if "master" in state:
+            new_state["master"] = new_master_tree
+        metrics = {"grad_norm": gnorm, "lr": lr}
+        return new_params, new_state, metrics
 
 
 def opt_state_axes(params_axes: PyTree, cfg: AdamWConfig) -> Dict[str, PyTree]:

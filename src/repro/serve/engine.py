@@ -24,6 +24,7 @@ correctness oracle and the benchmark's "before").
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -249,6 +250,9 @@ class _AdmitInfo:
     saved_bytes: float = 0.0
     saved_flops: float = 0.0
     gather_bytes: float = 0.0   # cached-window gather share of kv_bytes
+    # requests in this pass's prefill or extend call, as the profile's
+    # ``serve.admit`` span names them
+    uids: Any = ()
     # recovery share of the above (DESIGN.md §17): rows re-prefilling a
     # quarantined slot's context bill their exact per-row cost here too
     recovery_tokens: int = 0
@@ -318,6 +322,17 @@ from repro.models.costing import (attn_layers as _attn_layers,
                                   kv_bytes as _kv_bytes,
                                   matmul_weight_elems as _matmul_weight_elems,
                                   tree_bytes as _tree_bytes)
+
+
+def _step_span(step):
+    """Opens the profiler's step span ``serve`` (numbered by tick) around
+    one call of ``ServeEngine.step``."""
+    @functools.wraps(step)
+    def traced(self):
+        with jax.profiler.StepTraceAnnotation("serve",
+                                              step_num=self._tick_idx):
+            return step(self)
+    return traced
 
 
 class ServeEngine:
@@ -694,8 +709,9 @@ class ServeEngine:
             # page table) voids ALL of its lanes this tick
             bad = active & ~jnp.all(jnp.isfinite(logits), axis=(1, 2))
             ok = active & ~bad
-            n_acc, fix_tok, rng_new = spec_lib.speculative_accept(
-                logits, drafts, st.rng, st.temp)
+            with jax.named_scope("sample"):
+                n_acc, fix_tok, rng_new = spec_lib.speculative_accept(
+                    logits, drafts, st.rng, st.temp)
             rng_new = jnp.where(ok[:, None], rng_new, st.rng)
             # emission clamps: never exceed the token budget or the context
             # cap — exactly where the plain tick would have stopped
@@ -791,10 +807,11 @@ class ServeEngine:
                 [st.temp[:, None],
                  jnp.zeros((b, m - 1), st.temp.dtype)], axis=1)
             keys_f = jnp.broadcast_to(st.rng[:, None], (b, m, 2))
-            n_acc_f, fix_f, keys_new = spec_lib.speculative_accept(
-                logits.reshape(b * m, k + 1, -1),
-                drafts.reshape(b * m, k),
-                keys_f.reshape(b * m, 2), temp_f.reshape(b * m))
+            with jax.named_scope("sample"):
+                n_acc_f, fix_f, keys_new = spec_lib.speculative_accept(
+                    logits.reshape(b * m, k + 1, -1),
+                    drafts.reshape(b * m, k),
+                    keys_f.reshape(b * m, 2), temp_f.reshape(b * m))
             n_acc = n_acc_f.reshape(b, m)
             fix = fix_f.reshape(b, m)
             rng_new = keys_new.reshape(b, m, 2)[:, 0]
@@ -1061,9 +1078,11 @@ class ServeEngine:
 
     def _readback(self, x) -> np.ndarray:
         """Every device->host transfer goes through here (counted: the tick
-        hot path must do exactly one — the finished mask)."""
+        hot path must do exactly one — the finished mask). Each is a
+        ``serve.readback`` span in a profile."""
         self.host_readbacks += 1
-        return np.asarray(x)
+        with jax.profiler.TraceAnnotation("serve.readback"):
+            return np.asarray(x)
 
     def _checked_readback(self, x, validate, tick: int) -> np.ndarray:
         """Tick readback with transport-fault detection: the injector may
@@ -1444,6 +1463,7 @@ class ServeEngine:
                 self._recovering.discard(req.uid)
         return _AdmitInfo(
             admitted=len(reqs), prefill_tokens=toks_n, weight_passes=1,
+            uids=uids[:n],
             kv_bytes=self.kv_cache_bytes * len(reqs) / self.scfg.max_slots,
             flops=(2.0 * self._matmul_elems * toks_n
                    + 2.0 * self._n_attn * self._attn_dims * sq),
@@ -2113,6 +2133,7 @@ class ServeEngine:
             gather_bytes=gather_bytes,
             flops=(2.0 * self._matmul_elems * computed
                    + 2.0 * self._n_attn * self._attn_dims * attn_sq),
+            uids=uids[:len(work)],
             saved_bytes=self._kv_token_bytes * hit_tokens,
             saved_flops=(2.0 * self._matmul_elems * hit_tokens
                          + 2.0 * self._n_attn * self._attn_dims * hit_sq),
@@ -2220,8 +2241,15 @@ class ServeEngine:
 
     # -- main tick ------------------------------------------------------------
 
+    @_step_span
     def step(self) -> List[Request]:
-        """Admit + one fused decode tick. Returns finished requests."""
+        """Admit + one fused decode tick. Returns finished requests.
+
+        In a profile the tick is the step span ``serve`` and its host work
+        the spans ``serve.admit``, ``serve.compact``, ``serve.cow_barrier``,
+        ``serve.dispatch``, ``serve.readback``, ``serve.finish``,
+        ``serve.audit``, ``serve.journal`` and ``serve.snapshot``; the
+        admission and finish spans carry their requests' uids."""
         t0 = time.monotonic()
         tick = self._tick_idx
         finished: List[Request] = []
@@ -2247,12 +2275,15 @@ class ServeEngine:
         # pressures THIS tick's admission and a KV flip is what the decode
         # tick observes
         self._apply_host_faults(tick)
-        adm = self._admit(finished)
+        with jax.profiler.TraceAnnotation("serve.admit") as span:
+            adm = self._admit(finished)
+            span.set_metadata(uids=adm.uids)
         # admission-retry exhaustion sheds, queued by _defer_admission
         for req in self._pending_shed:
             self._shed_request(req, finished)
         self._pending_shed = []
-        moves = self._maybe_compact() if self.scfg.paged else 0
+        with jax.profiler.TraceAnnotation("serve.compact"):
+            moves = self._maybe_compact() if self.scfg.paged else 0
         # decoding slots only: mid-prefill paged slots and fork-reserved
         # child slots occupy a slot but don't produce decode tokens until
         # their final chunk / their parent's activation releases them
@@ -2262,7 +2293,8 @@ class ServeEngine:
         if self.scfg.paged and active:
             # COW write barrier (DESIGN.md §18): every page this tick
             # writes must be private to its slot BEFORE the tick runs
-            active = self._cow_barrier(active)
+            with jax.profiler.TraceAnnotation("serve.cow_barrier"):
+                active = self._cow_barrier(active)
         # live context per decoding slot: the tick attends lengths pos+1 =
         # prompt + generated-so-far — captured before finishes clear the
         # slot (page-granular KV read bill)
@@ -2284,8 +2316,9 @@ class ServeEngine:
                 # forked windows, run the folded verify, then resolve the
                 # winner's page adoption on the host
                 btables, bvalid = self._prepare_tree(active)
-                self.state, packed = self._tick(self.params, self.state,
-                                                poison, btables, bvalid)
+                with jax.profiler.TraceAnnotation("serve.dispatch"):
+                    self.state, packed = self._tick(self.params, self.state,
+                                                    poison, btables, bvalid)
                 arr = self._checked_readback(
                     packed, self._validate_tree_packed, tick)
                 done_mask = arr[0].astype(bool)
@@ -2297,8 +2330,9 @@ class ServeEngine:
                 for i in active:
                     self._host_gen[i] += int(n_emit[i])
             elif spec_k > 0:
-                self.state, packed = self._tick(self.params, self.state,
-                                                poison)
+                with jax.profiler.TraceAnnotation("serve.dispatch"):
+                    self.state, packed = self._tick(self.params, self.state,
+                                                    poison)
                 # the ONLY hot-path transfer (validated: the injector may
                 # garble/drop it, and the device buffer survives a re-read)
                 arr = self._checked_readback(
@@ -2311,8 +2345,9 @@ class ServeEngine:
                 for i in active:
                     self._host_gen[i] += int(n_emit[i])
             else:
-                self.state, packed = self._tick(self.params, self.state,
-                                                poison)
+                with jax.profiler.TraceAnnotation("serve.dispatch"):
+                    self.state, packed = self._tick(self.params, self.state,
+                                                    poison)
                 arr = self._checked_readback(
                     packed, self._validate_plain_packed, tick)
                 done_mask = arr[0].astype(bool)
@@ -2322,9 +2357,11 @@ class ServeEngine:
                         self._host_gen[i] += 1
                 emitted = int(sum(1 for i in active if not bad_mask[i]))
             for i in np.nonzero(done_mask)[0]:
-                if (self.slot_req[int(i)] is not None
-                        and int(i) not in self._prefilling):
-                    self._finish_slot(int(i), finished)
+                req = self.slot_req[int(i)]
+                if req is not None and int(i) not in self._prefilling:
+                    with jax.profiler.TraceAnnotation("serve.finish",
+                                                      uid=req.uid):
+                        self._finish_slot(int(i), finished)
             # sentinel-flagged slots made no progress and self-deactivated
             # on device — quarantine them: teardown + head-of-line
             # continuation. Unaffected slots' streams are untouched.
@@ -2413,7 +2450,8 @@ class ServeEngine:
             self._drift_check()
         if (guard.audit_interval > 0 and self.scfg.paged
                 and tick % guard.audit_interval == 0):
-            self._run_audit()
+            with jax.profiler.TraceAnnotation("serve.audit"):
+                self._run_audit()
         degraded = int(self._cur_spec_k != self.scfg.spec_k
                        or self._fell_back
                        or tick < self._compact_pause_until)
@@ -2471,19 +2509,21 @@ class ServeEngine:
             # fault schedules and deadlines key on absolute tick index),
             # THEN the snapshot — its journal_seq cut must sit after this
             # tick's record so replay resumes exactly at tick_idx
-            d_journal = self._journal.append_tick(
-                tick=tick,
-                finished=[[r.uid,
-                           [int(t) for t in r.generated],
-                           ([[int(t) for t in s] for s in r.nbest]
-                            if r.nbest is not None else None)]
-                          for r in finished])
+            with jax.profiler.TraceAnnotation("serve.journal"):
+                d_journal = self._journal.append_tick(
+                    tick=tick,
+                    finished=[[r.uid,
+                               [int(t) for t in r.generated],
+                               ([[int(t) for t in s] for s in r.nbest]
+                                if r.nbest is not None else None)]
+                              for r in finished])
             self.journal_bytes_total += d_journal
             d_snapshot = 0
             if (self.scfg.checkpoint_interval > 0
                     and self._tick_idx % self.scfg.checkpoint_interval
                     == 0):
-                d_snapshot = self._write_snapshot()
+                with jax.profiler.TraceAnnotation("serve.snapshot"):
+                    d_snapshot = self._write_snapshot()
             if self.accountant is not None:
                 self.accountant.observe_durability(
                     journal_bytes=d_journal, snapshot_bytes=d_snapshot,
@@ -2736,12 +2776,14 @@ class ServeEngine:
 def _sample(logits: jnp.ndarray, keys: jnp.ndarray, temp: jnp.ndarray
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-slot sampling: greedy where temp == 0, else categorical at temp,
-    each slot drawing from its own PRNG key. Returns (tokens, new keys)."""
-    split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)   # (B, 2, 2)
-    sub = split[:, 1]
-    new_keys = jnp.where((temp > 0)[:, None], split[:, 0], keys)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    tsafe = jnp.where(temp > 0, temp, 1.0)
-    sampled = jax.vmap(jax.random.categorical)(
-        sub, logits / tsafe[:, None]).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy), new_keys
+    each slot drawing from its own PRNG key. Returns (tokens, new keys).
+    Sits under the scope ``sample`` in a profile."""
+    with jax.named_scope("sample"):
+        split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # (B, 2, 2)
+        sub = split[:, 1]
+        new_keys = jnp.where((temp > 0)[:, None], split[:, 0], keys)
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        tsafe = jnp.where(temp > 0, temp, 1.0)
+        sampled = jax.vmap(jax.random.categorical)(
+            sub, logits / tsafe[:, None]).astype(jnp.int32)
+        return jnp.where(temp > 0, sampled, greedy), new_keys

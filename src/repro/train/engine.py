@@ -189,6 +189,11 @@ class TrainEngine:
         async; the metrics readback is the only sync point, after staging).
         The host-loop Trainer pays stage -> dispatch -> sync serially every
         step; here the pipeline cost hides behind device compute.
+
+        In a profile each tick is the step span ``train`` (numbered by
+        tick) holding ``train.dispatch``, ``train.stage``,
+        ``train.readback`` and ``train.account``; the first block's staging
+        before the loop is a ``train.stage`` span of its own.
         """
         assert self.pipeline is not None, "run() needs a pipeline"
         if num_steps <= 0:
@@ -201,37 +206,45 @@ class TrainEngine:
             left -= k
         last: Dict[str, float] = {}
         t_prev = time.monotonic()
-        staged = self._stage(self.step_num, plan[0])
+        with jax.profiler.TraceAnnotation("train.stage"):
+            staged = self._stage(self.step_num, plan[0])
         for i, k in enumerate(plan):
-            batches, tokens, samples = staged
-            self.params, self.opt_state, ms = self._tick(
-                self.params, self.opt_state, batches)
-            if i + 1 < len(plan):   # overlap: stage while the device runs
-                staged = self._stage(self.step_num + k, plan[i + 1])
-            ms_host = jax.device_get(ms)    # the ONE per-tick readback
-            self.host_readbacks += 1
-            now = time.monotonic()
-            wall = now - t_prev
-            t_prev = now
-            self.step_num += k
-            self.pipeline.restore({"step": self.step_num})
-            c = (self.cost.scaled(k) if self.cost is not None
-                 else energy.TrainStepCost(0.0, 0.0, 0.0, 0.0))
-            m = TrainStepMetrics(
-                steps=k, tokens=tokens, samples=samples, wall_s=wall,
-                loss=float(ms_host["loss"][-1]),
-                loss_mean=float(np.mean(ms_host["loss"])),
-                grad_norm=float(ms_host["grad_norm"][-1]),
-                fwd_flops=c.fwd_flops, bwd_flops=c.bwd_flops,
-                fwd_bytes=c.fwd_bytes, bwd_bytes=c.bwd_bytes,
-                opt_bytes=c.opt_bytes)
-            self.last_metrics = m
-            self.metrics_log.append(m)
-            if self.accountant is not None:
-                self.accountant.observe_train(m)
-            last = {"loss": m.loss, "grad_norm": m.grad_norm,
-                    "lr": float(ms_host["lr"][-1]),
-                    "step": float(self.step_num)}
+            # one step span per tick in a profile, numbered by tick
+            with jax.profiler.StepTraceAnnotation(
+                    "train", step_num=len(self.metrics_log)):
+                batches, tokens, samples = staged
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    self.params, self.opt_state, ms = self._tick(
+                        self.params, self.opt_state, batches)
+                if i + 1 < len(plan):   # overlap: stage while the device runs
+                    with jax.profiler.TraceAnnotation("train.stage"):
+                        staged = self._stage(self.step_num + k, plan[i + 1])
+                with jax.profiler.TraceAnnotation("train.readback"):
+                    ms_host = jax.device_get(ms)    # the ONE per-tick readback
+                self.host_readbacks += 1
+                with jax.profiler.TraceAnnotation("train.account"):
+                    now = time.monotonic()
+                    wall = now - t_prev
+                    t_prev = now
+                    self.step_num += k
+                    self.pipeline.restore({"step": self.step_num})
+                    c = (self.cost.scaled(k) if self.cost is not None
+                         else energy.TrainStepCost(0.0, 0.0, 0.0, 0.0))
+                    m = TrainStepMetrics(
+                        steps=k, tokens=tokens, samples=samples, wall_s=wall,
+                        loss=float(ms_host["loss"][-1]),
+                        loss_mean=float(np.mean(ms_host["loss"])),
+                        grad_norm=float(ms_host["grad_norm"][-1]),
+                        fwd_flops=c.fwd_flops, bwd_flops=c.bwd_flops,
+                        fwd_bytes=c.fwd_bytes, bwd_bytes=c.bwd_bytes,
+                        opt_bytes=c.opt_bytes)
+                    self.last_metrics = m
+                    self.metrics_log.append(m)
+                    if self.accountant is not None:
+                        self.accountant.observe_train(m)
+                    last = {"loss": m.loss, "grad_norm": m.grad_norm,
+                            "lr": float(ms_host["lr"][-1]),
+                            "step": float(self.step_num)}
         return last
 
     # -- aggregate metrics ----------------------------------------------------
