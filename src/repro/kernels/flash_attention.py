@@ -42,6 +42,51 @@ def _tile_mask(qi, ki, block_q: int, block_k: int, causal: bool, window: int):
     return mask
 
 
+def _kv_block_range(qi, block_q: int, block_k: int, n_k_blocks: int,
+                    causal: bool, window: int):
+    """First and last K block that query block ``qi`` needs under
+    :func:`_tile_mask`'s rule (scalars, for the index maps)."""
+    first, last = 0, n_k_blocks - 1
+    if causal:
+        last = jnp.minimum(last, ((qi + 1) * block_q - 1) // block_k)
+    if window > 0:
+        first = jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+    return first, last
+
+
+def _q_block_range(ki, block_q: int, block_k: int, n_q_blocks: int,
+                   causal: bool, window: int):
+    """First and last query block that K block ``ki`` is needed by."""
+    first, last = 0, n_q_blocks - 1
+    if causal:
+        first = (ki * block_k) // block_q
+    if window > 0:
+        last = jnp.minimum(last, ((ki + 1) * block_k + window - 2) // block_q)
+    return first, last
+
+
+def _clamp_block(i, first, last):
+    """Steps outside [first, last] re-map to the nearest needed block: the
+    pipeline sees the index of the step before (or after) and issues no DMA
+    for the fully masked tiles, whose compute ``_tile_mask`` skips."""
+    if isinstance(first, int) and isinstance(last, int):
+        return i                   # no mask limit: every block is needed
+    return jnp.minimum(jnp.maximum(i, first), last)
+
+
+def _dot_nt(a, b):
+    """a @ b.T on the MXU in the operands' storage dtype, fp32 result: a
+    bf16 x bf16 product is exact in fp32, so no fp32 copies of the tiles."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a.T @ b, fp32 operands and result."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _flash_kernel(*refs, scale: float, causal: bool, window: int,
                   block_q: int, block_k: int, n_k_blocks: int,
                   quantized: bool = False, save_lse: bool = False):
@@ -67,23 +112,24 @@ def _flash_kernel(*refs, scale: float, causal: bool, window: int,
     # skip fully-masked K blocks (the causal upper triangle / outside-window)
     @pl.when(jnp.any(mask))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale         # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, d)
         if quantized:
             # int8-KV fast path: per-row fp32 scale applied in-register,
             # right after the narrow HBM->VMEM DMA (DESIGN.md §12)
-            k = k * ks_ref[0, 0]                            # (bk, 1)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+            q = q_ref[0, 0].astype(jnp.float32) * scale     # (bq, d)
+            k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
+        else:
+            # scale the fp32 score, not a storage-dtype operand
+            s = _dot_nt(q_ref[0, 0], k_ref[0, 0]) * scale   # (bq, bk)
+            v = v_ref[0, 0].astype(jnp.float32)             # (bk, d)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]                                 # (bq, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                              # (bq, bk)
         alpha = jnp.exp(m_prev - m_new)                     # (bq, 1)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)                 # (bk, d)
-        if quantized:
-            v = v * vs_ref[0, 0]                            # (bk, 1)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -181,8 +227,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 # Two kernels: dQ sweeps K blocks innermost (grid b,h,nq,nk; dq tile
 # accumulates in VMEM scratch), dK/dV sweep Q blocks innermost (grid
 # b,h,nk,nq; dk/dv tiles in scratch). Both skip fully-masked tiles exactly
-# like the forward. GQA: dK/dV come out per *query* head and are
-# sum-reduced over the head group outside the kernel (fp32).
+# like the forward, and all three clamp the swept operand's block index to
+# the blocks the mask needs, so masked steps fetch nothing. q, k, v and dO
+# reach the MXU in their storage dtype; p and dS stay fp32. GQA: dK/dV come
+# out per *query* head and are sum-reduced over the head group outside the
+# kernel (fp32).
 # ---------------------------------------------------------------------------
 
 
@@ -200,19 +249,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(jnp.any(mask))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale         # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = _dot_nt(q_ref[0, 0], k_ref[0, 0]) * scale      # (bq, bk)
         # explicit mask (not just s=NEG_INF): rows whose every block is
         # masked have lse ~ NEG_INF and exp(s - lse) would be 1, not 0
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)   # (bq, bk)
-        do = do_ref[0, 0].astype(jnp.float32)               # (bq, d)
-        v = v_ref[0, 0].astype(jnp.float32)                 # (bk, d)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0])                     # (bq, bk)
-        acc_ref[...] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dp = _dot_nt(do_ref[0, 0], v_ref[0, 0])             # (bq, bk)
+        ds = p * (dp - delta_ref[0, 0])
+        acc_ref[...] += jax.lax.dot(ds, k_ref[0, 0].astype(jnp.float32),
+                                    preferred_element_type=jnp.float32)
 
     @pl.when(ki == n_k_blocks - 1)
     def _finish():
@@ -234,29 +278,29 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(jnp.any(mask))
     def _compute():
-        # q pre-scaled: dS^T @ (scale*Q) == scale * dS^T @ Q == dK directly
-        q = q_ref[0, 0].astype(jnp.float32) * scale         # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)   # (bq, bk)
-        do = do_ref[0, 0].astype(jnp.float32)               # (bq, d)
-        dv_acc[...] += jax.lax.dot_general(                 # p^T @ dO
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)                 # (bk, d)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0])                     # (bq, bk)
-        dk_acc[...] += jax.lax.dot_general(                 # dS^T @ q*scale
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        s = _dot_nt(q_ref[0, 0], k_ref[0, 0]) * scale      # (bq, bk)
+        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dv_acc[...] += _dot_tn(p, do_ref[0, 0].astype(jnp.float32))
+        dp = _dot_nt(do_ref[0, 0], v_ref[0, 0])             # (bq, bk)
+        ds = p * (dp - delta_ref[0, 0])
+        dk_acc[...] += _dot_tn(ds, q_ref[0, 0].astype(jnp.float32))
 
     @pl.when(qi == n_q_blocks - 1)
     def _finish():
-        # fp32 out: the GQA head-group sum happens outside the kernel
-        dk_ref[0, 0] = dk_acc[...]
+        # fp32 out: the GQA head-group sum happens outside the kernel;
+        # dK = scale * dS^T @ Q, scaled once here
+        dk_ref[0, 0] = dk_acc[...] * scale
         dv_ref[0, 0] = dv_acc[...]
+
+
+def _kv_sweep_spec(block_q, block_k, d, nk, rep, causal, window):
+    """K/V block spec of a (b, h, qi, ki) grid: the kv head of query head
+    ``hi``, K block ``ki`` clamped to the ones query block ``qi`` needs."""
+    def index(bi, hi, qi, ki):
+        first, last = _kv_block_range(qi, block_q, block_k, nk, causal,
+                                      window)
+        return bi, hi // rep, _clamp_block(ki, first, last), 0
+    return pl.BlockSpec((1, 1, block_k, d), index)
 
 
 def _fwd_with_lse(q, k, v, statics):
@@ -270,8 +314,7 @@ def _fwd_with_lse(q, k, v, statics):
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    kv_spec = pl.BlockSpec((1, 1, block_k, d),
-                           lambda bi, hi, qi, ki, rep=rep: (bi, hi // rep, ki, 0))
+    kv_spec = _kv_sweep_spec(block_q, block_k, d, nk, rep, causal, window)
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
@@ -314,53 +357,56 @@ def _flash_vjp_fwd(q, k, v, statics):
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(statics, res, dout):
-    scale, causal, window, block_q, block_k, interpret = statics
-    q, k, v, out, lse = res
-    b, sq, h, d = q.shape
-    _, sk, hkv, _ = k.shape
-    rep = h // hkv
+def _bwd_dq(qt, kt, vt, dot, lse, delta, *, scale, causal, window, block_q,
+            block_k, rep, interpret):
+    """dQ (B, H, Sq, D) in q's dtype; grid (b, h, nq, nk)."""
+    b, h, sq, d = qt.shape
+    sk = kt.shape[2]
     nq, nk = sq // block_q, sk // block_k
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    dot = dout.transpose(0, 2, 1, 3)
-    delta = jnp.sum(dot.astype(jnp.float32)
-                    * out.transpose(0, 2, 1, 3).astype(jnp.float32),
-                    axis=-1, keepdims=True)                # (B, H, Sq, 1)
     q_spec = pl.BlockSpec((1, 1, block_q, d),
                           lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     row_spec = pl.BlockSpec((1, 1, block_q, 1),
                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, d),
-                           lambda bi, hi, qi, ki, rep=rep: (bi, hi // rep, ki, 0))
-    dq = pl.pallas_call(
+    kv_spec = _kv_sweep_spec(block_q, block_k, d, nk, rep, causal, window)
+    return pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
                           n_k_blocks=nk),
         grid=(b, h, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qt.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(qt, kt, vt, dot, lse, delta)
-    # dK/dV grid: K blocks outer, Q sweep innermost
-    q_spec_t = pl.BlockSpec((1, 1, block_q, d),
-                            lambda bi, hi, ki, qi: (bi, hi, qi, 0))
-    row_spec_t = pl.BlockSpec((1, 1, block_q, 1),
-                              lambda bi, hi, ki, qi: (bi, hi, qi, 0))
-    kv_spec_t = pl.BlockSpec((1, 1, block_k, d),
-                             lambda bi, hi, ki, qi, rep=rep: (bi, hi // rep, ki, 0))
+
+
+def _bwd_dkv(qt, kt, vt, dot, lse, delta, *, scale, causal, window, block_q,
+             block_k, rep, interpret):
+    """Per-query-head fp32 dK and dV (B, H, Sk, D); grid (b, h, nk, nq):
+    K blocks outer, Q sweep innermost, clamped to the Q blocks that K block
+    ``ki`` is needed by."""
+    b, h, sq, d = qt.shape
+    sk = kt.shape[2]
+    nq, nk = sq // block_q, sk // block_k
+
+    def q_index(bi, hi, ki, qi):
+        first, last = _q_block_range(ki, block_q, block_k, nq, causal,
+                                     window)
+        return bi, hi, _clamp_block(qi, first, last), 0
+
+    q_spec = pl.BlockSpec((1, 1, block_q, d), q_index)
+    row_spec = pl.BlockSpec((1, 1, block_q, 1), q_index)
+    kv_spec = pl.BlockSpec((1, 1, block_k, d),
+                           lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
     kv_out_spec = pl.BlockSpec((1, 1, block_k, d),
                                lambda bi, hi, ki, qi: (bi, hi, ki, 0))
-    dkh, dvh = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
                           n_q_blocks=nq),
         grid=(b, h, nk, nq),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
-                  row_spec_t],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_out_spec, kv_out_spec],
         out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32)],
@@ -368,6 +414,25 @@ def _flash_vjp_bwd(statics, res, dout):
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
     )(qt, kt, vt, dot, lse, delta)
+
+
+def _flash_vjp_bwd(statics, res, dout):
+    scale, causal, window, block_q, block_k, interpret = statics
+    q, k, v, out, lse = res
+    b, sq, h, d = q.shape
+    _, sk, hkv, _ = k.shape
+    rep = h // hkv
+    qt = q.transpose(0, 2, 1, 3)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    dot = dout.transpose(0, 2, 1, 3)
+    delta = jnp.sum(dot.astype(jnp.float32)
+                    * out.transpose(0, 2, 1, 3).astype(jnp.float32),
+                    axis=-1, keepdims=True)                # (B, H, Sq, 1)
+    kw = dict(scale=scale, causal=causal, window=window, block_q=block_q,
+              block_k=block_k, rep=rep, interpret=interpret)
+    dq = _bwd_dq(qt, kt, vt, dot, lse, delta, **kw)
+    dkh, dvh = _bwd_dkv(qt, kt, vt, dot, lse, delta, **kw)
     # GQA: per-query-head dK/dV sum over the head group (fp32), then layout
     # back to (B, Sk, Hkv, D)
     dk = dkh.reshape(b, hkv, rep, sk, d).sum(axis=2)

@@ -295,9 +295,9 @@ def paged_prefill_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     return out[:, :c]
 
 
-def _round_up_pow2(n: int) -> int:
+def _round_up_pow2(n: int, cap: int = 128) -> int:
     p = 8
-    while p < n and p < 128:
+    while p < n and p < cap:
         p *= 2
     return p
 
@@ -306,10 +306,56 @@ def _round_up_pow2(n: int) -> int:
 # Training fast path: differentiable wrappers (custom-VJP kernels, §13)
 # -----------------------------------------------------------------------------
 
+# Training flash tiles. Past 128 rows a tile grows in powers of two while
+# it divides the row padded to 128 (so padding is what a 128 tile gives)
+# and stays within a cap: FLASH_TILE_MAX, halved while the kernels' VMEM
+# need exceeds FLASH_VMEM_BUDGET, and halved while two tiles span more than
+# a sliding window, so that most of a windowed row's tiles lie in it.
+FLASH_TILE_MAX = 1024
+# v5e's default scoped VMEM limit for a Mosaic kernel
+FLASH_VMEM_BUDGET = 16 * 2**20
+
+
+def flash_vmem_bytes(block_q: int, block_k: int, d: int,
+                     itemsize: int) -> int:
+    """VMEM the training flash kernels need for one (block_q, block_k)
+    tile, an upper bound fitted to the v5e compiler's need for the dK/dV
+    kernel, the largest of the three: two fp32 (bq, bk) tiles; per q row,
+    q and dO double-buffered in ``itemsize`` bytes and their fp32 copies;
+    per k row, k and v double-buffered, fp32 dK and dV double-buffered and
+    their accumulators; 1 MiB of the compiler's own. Rows pad to 128
+    lanes."""
+    lanes = -(-d // 128) * 128
+    q_side = block_q * lanes * (4 * itemsize + 8)
+    k_side = block_k * lanes * (4 * itemsize + 24)
+    return 8 * block_q * block_k + q_side + k_side + 2**20
+
+
+def flash_train_tiles(sq: int, sk: int, d: int, window: int,
+                      itemsize: int) -> tuple:
+    """(block_q, block_k) of the training flash kernels, from shapes only.
+    Rows of 128 or fewer keep the pow2 envelope of the row, as the
+    inference wrapper gives."""
+    cap = FLASH_TILE_MAX
+    while cap > 128 and flash_vmem_bytes(cap, cap, d, itemsize) \
+            > FLASH_VMEM_BUDGET:
+        cap //= 2
+    while window > 0 and cap > 128 and 2 * cap > window:
+        cap //= 2
+
+    def tile(n):
+        t = _round_up_pow2(n)
+        padded = -(-n // t) * t
+        while 2 * t <= cap and padded % (2 * t) == 0:
+            t *= 2
+        return t
+    return tile(sq), tile(sk)
+
+
 def flash_attention_train(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                           scale: Optional[float] = None, causal: bool = True,
-                          window: int = -1, block_q: int = 128,
-                          block_k: int = 128,
+                          window: int = -1, block_q: Optional[int] = None,
+                          block_k: Optional[int] = None,
                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Differentiable flash attention (custom-VJP Pallas kernels).
 
@@ -317,13 +363,19 @@ def flash_attention_train(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     through it runs the fused backward kernels (recompute-from-lse; no
     O(Sq*Sk) probability tensor) instead of failing on the pallas_call.
     Padding/slicing here is plain jnp, so its VJP composes with the kernel's.
+    Tiles come from :func:`flash_train_tiles`; ``block_q``/``block_k``
+    override them (shrunk to the row's pow2 envelope, as in
+    :func:`flash_attention`).
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     b, sq, h, d = q.shape
     sk = k.shape[1]
     scale = scale if scale is not None else d ** -0.5
-    bq = min(block_q, _round_up_pow2(sq))
-    bk = min(block_k, _round_up_pow2(sk))
+    bq, bk = flash_train_tiles(sq, sk, d, window, q.dtype.itemsize)
+    if block_q is not None:
+        bq = min(block_q, _round_up_pow2(sq, block_q))
+    if block_k is not None:
+        bk = min(block_k, _round_up_pow2(sk, block_k))
     # same ragged-shape escape as the inference wrapper: padded keys are
     # only hidden by causal masking when sq <= sk
     if (-sk) % bk != 0 and (not causal or sq > sk):

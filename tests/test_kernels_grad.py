@@ -53,6 +53,17 @@ FLASH_CASES = [
     (13, 16, 2, 2, 8, True, -1, 8, 16),         # sq < sk (chunked prefill)
     (16, 16, 2, 2, 16, True, -1, 16, 8),        # asymmetric blocks
     (9, 9, 2, 2, 8, True, 4, 8, 8),             # odd + window
+    # tiles past 128, several per row: the clamped index maps at work
+    (512, 512, 2, 2, 8, True, -1, 256, 128),    # large, rectangular
+    (512, 512, 2, 2, 8, True, -1, 128, 256),    # large, bk > bq
+    (512, 512, 2, 2, 8, True, -1, None, None),  # the shape rule's tiles
+    (300, 300, 2, 2, 8, True, -1, None, None),  # rule + padded tail
+    (64, 64, 2, 2, 8, True, -1, 16, 8),         # causal, 8 K tiles a row
+    (64, 64, 2, 2, 8, True, 20, 16, 16),        # window cuts through tiles
+    (64, 64, 2, 2, 8, True, 20, 32, 8),         # window, rectangular
+    (48, 48, 2, 2, 8, False, 12, 16, 16),       # window, non-causal
+    (64, 64, 6, 2, 8, True, -1, 32, 16),        # GQA, rep 3
+    (64, 64, 4, 1, 8, True, 24, 16, 32),        # MQA + window
 ]
 
 
@@ -79,6 +90,55 @@ def test_flash_attention_grad_parity(sq, sk, h, hkv, d, causal, window,
         np.testing.assert_allclose(
             np.asarray(g, np.float32), np.asarray(w, np.float32),
             **TOL[dtype], err_msg=f"d{name}")
+
+
+def test_flash_train_tiles_rule():
+    """The training tiles come from shapes: large at StarCoder2-7B's row,
+    the pow2 envelope (at most 128) for short rows, capped by a sliding
+    window, and within the VMEM budget at every head_dim the configs use."""
+    tiles = kops.flash_train_tiles
+    assert tiles(2048, 2048, 128, -1, 2) == (1024, 1024)
+    for s in (1, 8, 13, 64, 100, 128):
+        t = kops._round_up_pow2(s)
+        assert tiles(s, s, 128, -1, 2) == (t, t) and t <= 128
+    big = tiles(4096, 4096, 128, -1, 2)[0]
+    windowed = tiles(4096, 4096, 128, 1024, 2)[0]
+    assert windowed < big and 2 * windowed <= 1024
+    for d in (64, 112, 128, 256):
+        for itemsize in (2, 4):
+            bq, bk = tiles(2048, 2048, d, -1, itemsize)
+            assert bq >= 128 and bk >= 128
+            assert (kops.flash_vmem_bytes(bq, bk, d, itemsize)
+                    <= kops.FLASH_VMEM_BUDGET), (d, itemsize, bq, bk)
+    # the tile always divides the row as padded to 128: no extra padding
+    for s in range(129, 4200, 37):
+        bq, bk = tiles(s, s, 128, -1, 2)
+        assert -(-s // bq) * bq == -(-s // 128) * 128 == -(-s // bk) * bk
+
+
+@pytest.mark.parametrize("causal,window", [(True, -1), (True, 5),
+                                           (True, 40), (False, 9)])
+@pytest.mark.parametrize("bq,bk", [(8, 8), (16, 8), (8, 32)])
+def test_flash_clamped_block_ranges_match_tile_mask(causal, window, bq, bk):
+    """The index maps' block ranges are exactly the tiles that
+    ``_tile_mask`` keeps: a needed block is never re-mapped away, and every
+    re-mapped step is one the kernel skips."""
+    from repro.kernels import flash_attention as fa
+    n = 64
+    nq, nk = n // bq, n // bk
+    live = np.array([[bool(jnp.any(fa._tile_mask(qi, ki, bq, bk, causal,
+                                                 window)))
+                      for ki in range(nk)] for qi in range(nq)])
+    for qi in range(nq):
+        first, last = fa._kv_block_range(qi, bq, bk, nk, causal, window)
+        want = [ki for ki in range(nk) if live[qi, ki]]
+        assert want == list(range(int(first), int(last) + 1)), (qi, want)
+        clamped = [int(fa._clamp_block(ki, first, last)) for ki in range(nk)]
+        assert set(clamped) == set(want)
+    for ki in range(nk):
+        first, last = fa._q_block_range(ki, bq, bk, nq, causal, window)
+        want = [qi for qi in range(nq) if live[qi, ki]]
+        assert want == list(range(int(first), int(last) + 1)), (ki, want)
 
 
 def test_flash_attention_fwd_matches_inference_wrapper():

@@ -1,5 +1,7 @@
 """The main path's Pallas kernels compile for a TPU v5e at StarCoder2-7B
-widths.
+widths, and the training flash kernels also at a windowed (gemma3-27b's
+local layers) and an MQA (granite-34b) width, each with the tiles that
+``ops.flash_train_tiles`` chooses.
 
 Interpret mode (every other kernel test) accepts kernels that the chip's
 compiler refuses for tiling or VMEM limits. These tests hand the real TPU
@@ -101,9 +103,13 @@ def _dense_decode():
                 ((SLOTS,), jnp.int32)]
 
 
-_FLASH = [((1, 2048, H, D), jnp.bfloat16),
-          ((1, 2048, HKV, D), jnp.bfloat16),
-          ((1, 2048, HKV, D), jnp.bfloat16)]
+def _flash_shapes(h=H, hkv=HKV):
+    return [((1, 2048, h, D), jnp.bfloat16),
+            ((1, 2048, hkv, D), jnp.bfloat16),
+            ((1, 2048, hkv, D), jnp.bfloat16)]
+
+
+_FLASH = _flash_shapes()
 
 
 def _flash_fwd():
@@ -112,13 +118,14 @@ def _flash_fwd():
     return fn, _FLASH
 
 
-def _flash_bwd():
+def _flash_bwd(shapes=_FLASH, window=-1):
     def fn(q, k, v):
         def loss(q, k, v):
-            o = kops.flash_attention_train(q, k, v, interpret=False)
+            o = kops.flash_attention_train(q, k, v, window=window,
+                                           interpret=False)
             return jnp.sum(o.astype(jnp.float32))
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    return fn, _FLASH
+    return fn, shapes
 
 
 _INT8_MM = [((256, D_MODEL), jnp.bfloat16), ((D_MODEL, D_FF), jnp.int8),
@@ -140,6 +147,9 @@ def _int8_bwd():
     return fn, _INT8_MM
 
 
+_FLASH_KERNELS = {"_flash_kernel", "_flash_bwd_dq_kernel",
+                  "_flash_bwd_dkv_kernel"}
+
 # case -> (shape-and-function factory, the Pallas kernels the compiled
 # program must keep)
 KERNELS = {
@@ -152,8 +162,14 @@ KERNELS = {
                              {"_paged_prefill_kernel"}),
     "dense_decode": (_dense_decode, {"_decode_kernel"}),
     "flash_fwd": (_flash_fwd, {"_flash_kernel"}),
-    "flash_bwd": (_flash_bwd, {"_flash_kernel", "_flash_bwd_dq_kernel",
-                               "_flash_bwd_dkv_kernel"}),
+    "flash_bwd": (_flash_bwd, _FLASH_KERNELS),
+    # gemma3-27b's local layers: 32/16 heads of 128, window 1024
+    "flash_bwd_window1024": (
+        lambda: _flash_bwd(_flash_shapes(32, 16), window=1024),
+        _FLASH_KERNELS),
+    # granite-34b: MQA, 48 heads over one kv head of 128
+    "flash_bwd_mqa": (lambda: _flash_bwd(_flash_shapes(48, 1)),
+                      _FLASH_KERNELS),
     "int8_matmul_fwd": (_int8_fwd, {"_int8_matmul_kernel"}),
     "int8_matmul_bwd": (_int8_bwd, {"_int8_bwd_dx_kernel"}),
 }
