@@ -71,3 +71,6 @@ class Run:
     trace: object = None
     peaks: dict = dataclasses.field(default_factory=dict)
     notes: List[str] = dataclasses.field(default_factory=list)
+    # the model family module (``bench/families/<family>.py``) whose
+    # counts the readers use
+    family: object = None

@@ -1,7 +1,9 @@
-"""The one adapter between the benchmark and the program under test
+"""The adapter between the benchmark and the program under test
 (``src/repro``). Everything else in the benchmark reads only what this
 module hands it: engines built through the program's own launchers, the
-program's counters, and the kernel names in its compiled programs.
+program's counters, and the kernel names in its compiled programs; and
+the program's model config, which each family's ``program_config``
+(``bench/families/``) builds.
 
 The serving engine reports emitted tokens only when a request finishes;
 per-tick emissions are read here from its host mirrors
@@ -12,7 +14,6 @@ a public per-tick emission record would replace them.
 
 from __future__ import annotations
 
-import dataclasses
 import importlib
 import pathlib
 import sys
@@ -26,12 +27,6 @@ from . import trace as trace_lib
 
 CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
 
-# configuration-file keys that must equal the program's own config
-_WIDTHS = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
-           "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-           "intermediate_size": "d_ff", "vocab_size": "vocab",
-           "mlp_gated": "mlp_gated", "tie_word_embeddings": "tie_embeddings"}
-
 
 def import_program():
     """Put ``<checkout>/src`` on the path and import the program. Raises
@@ -40,28 +35,6 @@ def import_program():
     if src not in sys.path:
         sys.path.insert(0, src)
     return importlib.import_module("repro")
-
-
-def lm_config(model: dict):
-    """The program's model config named by ``program_config``
-    (``<module>`` or ``<module>:smoke`` for its CPU-sized sibling), at the
-    file's depth and RoPE base; every width in the file must equal the
-    program's."""
-    import_program()
-    name, _, variant = model["program_config"].partition(":")
-    mod = importlib.import_module(f"repro.configs.{name}")
-    make = mod.make_smoke if variant == "smoke" else mod.make_config
-    cfg = dataclasses.replace(make(), repeats=model["num_hidden_layers"],
-                              rope_theta=float(model["rope_theta"]))
-    for key, attr in _WIDTHS.items():
-        got = getattr(cfg, attr)
-        if key in model and got != model[key]:
-            raise ValueError(f"{key}: the file says {model[key]}, the "
-                             f"program's config {attr}={got}")
-    if cfg.act != "gelu" or cfg.padded_vocab != cfg.vocab:
-        raise ValueError("the reference assumes a tanh-GELU MLP and an "
-                         "unpadded vocabulary")
-    return cfg
 
 
 # -- serving --------------------------------------------------------------------

@@ -1,13 +1,13 @@
 """Reductions shared by the metric readers in ``bench/metrics``. Each
 returns ``None`` where its run has nothing to read (no trace, no call of
 the program or kernel in the window, no peak for the device), never 0
-for a share of a peak or a roofline."""
+for a share of a peak or a roofline. Operations and bytes come from the
+counts of the run's model family (``run.family``)."""
 
 from __future__ import annotations
 
 from typing import Iterable, Optional
 
-from . import costs
 from . import trace as trace_lib
 from .common import nearest_rank
 
@@ -45,7 +45,7 @@ def decode_hbm(run) -> Optional[float]:
     if not _ready(run):
         return None
     t = trace_lib.module_ns(run.trace, TICK)
-    byt = sum(costs.decode_bytes(run.model, s.decode_ctxs)
+    byt = sum(run.family.decode_bytes(run.model, s.decode_ctxs)
               for s in run.steps if s.decode_ctxs)
     if not t or not byt:
         return None
@@ -53,12 +53,12 @@ def decode_hbm(run) -> Optional[float]:
 
 
 def _extend_flops(run) -> float:
-    return sum(costs.extend_flops(run.model, rows)
+    return sum(run.family.extend_flops(run.model, rows)
                for _, rows in run.extend_rows if rows)
 
 
 def _decode_flops(run) -> float:
-    return sum(costs.decode_flops(run.model, s.decode_ctxs)
+    return sum(run.family.decode_flops(run.model, s.decode_ctxs)
                for s in run.steps if s.decode_ctxs)
 
 
@@ -106,13 +106,13 @@ def roofline(run, kernels: Iterable[str], work) -> Optional[float]:
 
 def paged_decode_roofline(run) -> Optional[float]:
     return roofline(run, ["_paged_kernel"],
-                    [costs.paged_decode_kernel(run.model, s.decode_ctxs)
+                    [run.family.paged_decode_kernel(run.model, s.decode_ctxs)
                      for s in run.steps if s.decode_ctxs])
 
 
 def paged_prefill_roofline(run) -> Optional[float]:
     return roofline(run, ["_paged_prefill_kernel"],
-                    [costs.paged_prefill_kernel(run.model, rows)
+                    [run.family.paged_prefill_kernel(run.model, rows)
                      for _, rows in run.extend_rows if rows])
 
 
@@ -124,7 +124,7 @@ def train_mfu(run) -> Optional[float]:
     t = trace_lib.module_ns(run.trace, TICK)
     if not t:
         return None
-    f = costs.train_flops_per_token(run.model, run.mix["seq_len"])
+    f = run.family.train_flops_per_token(run.model, run.mix["seq_len"])
     return (100.0 * f * run.train["traced_tokens"]
             / (t / 1e9 * run.peaks["bf16_flops"]))
 
@@ -133,6 +133,6 @@ def flash_bwd_roofline(run) -> Optional[float]:
     if not run.train.get("traced_steps"):
         return None
     return roofline(run, ["_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"],
-                    [costs.flash_bwd_kernels(run.model, run.mix["batch"],
+                    [run.family.attention_kernels(run.model, run.mix["batch"],
                                              run.mix["seq_len"],
                                              run.train["traced_steps"])])

@@ -4,14 +4,18 @@ metrics and print the result.
 
 Names are looked up in ``BENCHMARK.json``: a workload names its
 configuration (``bench/configs/<name>.json`` through the configuration's
-``file``) and its traffic mix (``bench/traffic/<mix>.json``); every metric
-is read by ``bench/metrics/<metric>.py``, whose ``read(run)`` returns a
-number or ``None`` where it finds nothing to read. The mix's ``kind``
-picks the module (``harness/serve.py`` or ``harness/train.py``).
+``file``) and its traffic mix (``bench/traffic/<mix>.json``); the
+configuration names its model family (``bench/families/<family>.py``,
+whose module holds the architecture's weights, reference and counts);
+every metric is read by ``bench/metrics/<metric>.py``, whose
+``read(run)`` returns a number or ``None`` where it finds nothing to
+read. The mix's ``kind`` picks the module (``harness/serve.py`` or
+``harness/train.py``).
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import math
@@ -45,17 +49,32 @@ def cell_files(root: pathlib.Path, bench: dict, name: str):
     cell = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     model = load_json(root / conf["file"])
+    if "family" not in model:
+        raise ValueError(f"{conf['file']} names no model family: give it "
+                         f"a \"family\" key, the name of a module under "
+                         f"bench/families")
     mix = load_json(root / "bench" / "traffic" / f"{cell['traffic']}.json")
     return cell, model, mix
 
 
-def metric_reader(root: pathlib.Path, name: str):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+@functools.lru_cache(maxsize=None)
+def _module(path: pathlib.Path, name: str):
+    """The module in the file ``path``, loaded once per process."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(root: pathlib.Path, name: str):
+    path = root / "bench" / "metrics" / f"{name}.py"
+    return _module(path, f"bench_metric_{name.replace('.', '_')}").read
+
+
+def load_family(root: pathlib.Path, name: str):
+    """The model family module ``bench/families/<name>.py``."""
+    return _module(root / "bench" / "families" / f"{name}.py",
+                   f"bench_family_{name}")
 
 
 def metrics_for(bench: dict, cell: str, traced: bool):
@@ -80,6 +99,7 @@ def run_cell(root: pathlib.Path, name: str, seed: int, seconds: float,
     cell, model_f, mix_f = cell_files(root, bench, name)
     model = model or model_f
     mix = mix or mix_f
+    family = load_family(root, model["family"])
     devices = jax.devices()
     if require_tpu and (devices[0].platform != "tpu"
                         or len(devices) < cell["chips"]):
@@ -94,13 +114,13 @@ def run_cell(root: pathlib.Path, name: str, seed: int, seconds: float,
     try:
         if mix["kind"] == "serve":
             from .serve import serve_check, serve_run
-            run = serve_run(cell, model, mix, seed, seconds, trace_dir,
-                            t_proc, dev, fault=fault)
+            run = serve_run(cell, family, model, mix, seed, seconds,
+                            trace_dir, t_proc, dev, fault=fault)
             checks = serve_check(run, model, seed, control=control)
         else:
             from .train import train_check, train_run
-            run = train_run(cell, model, mix, seed, seconds, trace_dir,
-                            t_proc, dev, fault=fault)
+            run = train_run(cell, family, model, mix, seed, seconds,
+                            trace_dir, t_proc, dev, fault=fault)
             checks = train_check(run, model, seed, control=control)
         run.peaks = peaks
         breakdown = None

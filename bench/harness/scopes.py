@@ -33,7 +33,6 @@ import gc
 import re
 from typing import Dict, Optional
 
-from . import costs
 from . import trace as trace_lib
 
 TICK = "jit_tick"
@@ -88,26 +87,17 @@ def idle_by_program_span(tr: trace_lib.Trace) -> Dict[str, float]:
                                     n=None))
 
 
-def param_count(m: dict) -> int:
-    """Parameters of the model in the configuration ``m``: the layers'
-    matrices and norm scales, the (tied) embedding and the final norm."""
-    d = m["hidden_size"]
-    emb = m["vocab_size"] * d * (1 if m.get("tie_word_embeddings") else 2)
-    layers = m["num_hidden_layers"] * (costs.layer_matmul_params(m) + 2 * d)
-    return emb + layers + d
-
-
-def train_tick_hlo(model: dict, mix: dict, seed: int) -> str:
+def train_tick_hlo(family, model: dict, mix: dict, seed: int) -> str:
     """Compiled HLO text of the train tick as a run of this configuration
-    and mix builds it."""
+    of the model family ``family`` and this mix builds it."""
     import jax
     import jax.numpy as jnp
     from . import program
     from .traffic import TrainTraffic
-    from .weights import make_weights
     train = model["train"]
     eng = program.train_engine(
-        make_weights(model, seed, train["dtype"]), program.lm_config(model),
+        family.make_weights(model, seed, train["dtype"]),
+        family.program_config(model),
         TrainTraffic(mix, seed, model["vocab_size"]), train)
     block = jax.ShapeDtypeStruct(
         (train["steps_per_tick"], mix["batch"], mix["seq_len"]), jnp.int32)
@@ -130,7 +120,7 @@ def train_scopes(run) -> Optional[Dict[str, str]]:
     if (run.trace is None or not run.train.get("traced_steps")
             or TICK not in run.kernels):
         return None
-    text = train_tick_hlo(run.model, run.mix, run.seed)
+    text = train_tick_hlo(run.family, run.model, run.mix, run.seed)
     if trace_lib.kernel_instructions(text) != run.kernels[TICK]:
         run.notes.append("scopes: the train tick compiled again is not the "
                          "program that ran; no phase is read")
@@ -171,14 +161,15 @@ def _phase_s(run, name: str) -> Optional[float]:
 
 
 def train_phase_mfu(run, name: str) -> Optional[float]:
-    """Model FLOPs of the traced steps' forward (a third of
-    ``costs.train_flops_per_token``) or backward (the other two thirds)
+    """Model FLOPs of the traced steps' forward (a third of the family's
+    ``train_flops_per_token``) or backward (the other two thirds)
     over that phase's self time at the chip's bf16 peak, in percent."""
     t = _phase_s(run, name)
     if t is None:
         return None
     share = {"forward": 1.0 / 3.0, "backward": 2.0 / 3.0}[name]
-    f = share * costs.train_flops_per_token(run.model, run.mix["seq_len"])
+    f = share * run.family.train_flops_per_token(run.model,
+                                                 run.mix["seq_len"])
     return 100.0 * f * run.train["traced_tokens"] / (
         t * run.peaks["bf16_flops"])
 
@@ -189,7 +180,7 @@ def adamw_hbm_share(run) -> Optional[float]:
     t = _phase_s(run, "optimizer")
     if t is None:
         return None
-    byt = (ADAMW_BYTES_PER_PARAM * param_count(run.model)
+    byt = (ADAMW_BYTES_PER_PARAM * run.family.param_count(run.model)
            * run.train["traced_steps"])
     return 100.0 * byt / (t * run.peaks["hbm_bytes_per_s"])
 

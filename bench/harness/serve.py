@@ -42,15 +42,14 @@ class ReqRec:
     done_t: Optional[float] = None
 
 
-def serve_run(cell: dict, model: dict, mix: dict, seed: int, seconds: float,
-              trace_dir: Optional[str], t_proc: float, device,
-              fault=None) -> Run:
+def serve_run(cell: dict, family, model: dict, mix: dict, seed: int,
+              seconds: float, trace_dir: Optional[str], t_proc: float,
+              device, fault=None) -> Run:
     import jax
-    from .weights import make_weights
     serve = model["serve"]
     clog = CompileLog()
-    cfg = program.lm_config(model)
-    params = make_weights(model, seed, serve["dtype"])
+    cfg = family.program_config(model)
+    params = family.make_weights(model, seed, serve["dtype"])
     eng = program.serve_engine(params, cfg, serve, seed)
     if fault is not None:
         fault(eng)
@@ -151,7 +150,7 @@ def serve_run(cell: dict, model: dict, mix: dict, seed: int, seconds: float,
     run = Run(cell=cell, model=model, mix=mix, seed=seed,
               window_s=win1 - win0, setup_s=setup_s,
               compiles_in_window=compiles, memory_peak=peak,
-              kernels=kernels)
+              kernels=kernels, family=family)
     run.steps = [s for s in steps if s.t0 >= win0]
     run.extend_rows = [(t, rows) for t, rows in ext_rows if t >= win0]
     run.notes.append(f"guard counters {guards}")
@@ -234,14 +233,13 @@ def serve_check(run: Run, model: dict, seed: int, control: bool = False
     at the same positions of the same prompts and served tokens, the gap
     of the token that the reference with int8 weights puts first, in the
     program's place."""
-    from . import reference
-    from .weights import make_weights
-    w = make_weights(model, seed, model["serve"]["dtype"])
+    family = run.family
+    w = family.make_weights(model, seed, model["serve"]["dtype"])
     worst = 0.0
     n = 0
     for rec in run.sample:
-        gaps = reference.served_gaps(w, model, rec.prompt, rec.served,
-                                     control=control)
+        gaps = family.served_gaps(w, model, rec.prompt, rec.served,
+                                  control=control)
         worst = max(worst, float(np.max(gaps)))
         n += len(gaps)
     run.notes.append(f"{'int8 control' if control else 'reference'} over "

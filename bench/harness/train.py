@@ -40,26 +40,25 @@ from .traffic import TrainTraffic
 TRACE_TICKS = 10
 
 
-def train_run(cell: dict, model: dict, mix: dict, seed: int, seconds: float,
-              trace_dir: Optional[str], t_proc: float, device,
-              fault=None) -> Run:
+def train_run(cell: dict, family, model: dict, mix: dict, seed: int,
+              seconds: float, trace_dir: Optional[str], t_proc: float,
+              device, fault=None) -> Run:
     import jax
     from . import reference
-    from .weights import make_weights
     train = model["train"]
     clog = CompileLog()
-    cfg = program.lm_config(model)
+    cfg = family.program_config(model)
     feed = TrainTraffic(mix, seed, model["vocab_size"])
     k = train["steps_per_tick"]
-    eng = program.train_engine(make_weights(model, seed, train["dtype"]),
-                               cfg, feed, train)
+    eng = program.train_engine(
+        family.make_weights(model, seed, train["dtype"]), cfg, feed, train)
     if fault is not None:
         fault(eng)
     ticks = program.TickLog(eng)
     eng.run(k)
     first = jax.device_get(ticks.outs[0])
     mom, master = program.opt_state_view(eng)
-    w0 = make_weights(model, seed, train["dtype"])
+    w0 = family.make_weights(model, seed, train["dtype"])
     prog = {
         "loss": [float(x) for x in first["loss"]],
         "grad_norm": [float(x) for x in first["grad_norm"]],
@@ -101,7 +100,7 @@ def train_run(cell: dict, model: dict, mix: dict, seed: int, seconds: float,
     gc.collect()
     run = Run(cell=cell, model=model, mix=mix, seed=seed, window_s=window,
               setup_s=setup_s, compiles_in_window=compiles,
-              memory_peak=peak, kernels=kernels)
+              memory_peak=peak, kernels=kernels, family=family)
     run.attempted = n_ticks * k
     run.failed = sum(1 for x in losses if not math.isfinite(x))
     run.train = {"ticks": n_ticks, "steps": n_ticks * k,
@@ -129,19 +128,19 @@ def _stop_trace(span) -> None:
 
 def train_check(run: Run, model: dict, seed: int, control: bool = False
                 ) -> Dict[str, float]:
-    """The first tick's numbers against the reference's. ``control``: the
-    reference with int8 weights (:func:`reference.fake_int8`) in the
-    program's place."""
+    """The first tick's numbers against the reference's: the AdamW loop
+    over the family's reference loss. ``control``: the reference with
+    int8 weights (:func:`reference.fake_int8`) in the program's place."""
     from . import reference
-    from .weights import make_weights
-    train, mix = model["train"], run.mix
+    train, mix, family = model["train"], run.mix, run.family
     feed = TrainTraffic(mix, seed, model["vocab_size"])
     batches = [feed.batch_at(i) for i in range(train["steps_per_tick"])]
-    w = make_weights(model, seed, train["dtype"])
-    ref = reference.train_steps(w, model, train, batches)
+    w = family.make_weights(model, seed, train["dtype"])
+    ref = reference.train_steps(family.loss, w, model, train, batches)
     prog = run.program_train
     if control:
-        prog = reference.train_steps(w, model, train, batches, quant=True)
+        prog = reference.train_steps(family.loss, w, model, train, batches,
+                                     quant=True)
         run.notes.append(f"int8 control losses {prog['loss']}")
     run.notes.append(f"reference losses {ref['loss']}, global gradient "
                      f"norms {ref['grad_norm']}")

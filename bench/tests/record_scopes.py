@@ -33,7 +33,8 @@ def main():
     print(json.dumps(out), flush=True)
     _, model, mix = runner.cell_files(
         BENCH.parent, runner.load_benchmark(BENCH.parent), name)
-    names = scopes.op_names(scopes.train_tick_hlo(model, mix, seed))
+    family = runner.load_family(BENCH.parent, model["family"])
+    names = scopes.op_names(scopes.train_tick_hlo(family, model, mix, seed))
     with open(os.path.join(dest, "scopes.json"), "w") as f:
         json.dump(names, f, indent=1, sort_keys=True)
 

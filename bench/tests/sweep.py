@@ -32,10 +32,12 @@ def main():
     root = BENCH.parent
     bench = runner.load_benchmark(root)
     cell, model, mix = runner.cell_files(root, bench, name)
+    family = runner.load_family(root, model["family"])
     for rate in [float(r) for r in sys.argv[4:]]:
         t = time.perf_counter()
-        run = serve_run(cell, model, dict(mix, loop="open", rate_per_s=rate),
-                        seed, seconds, None, t, jax.devices()[0])
+        run = serve_run(cell, family, model,
+                        dict(mix, loop="open", rate_per_s=rate), seed,
+                        seconds, None, t, jax.devices()[0])
         q = [s.queue for s in run.steps]
         ttft, itl = run.samples["ttft_s"], run.samples["itl_s"]
         print(json.dumps({

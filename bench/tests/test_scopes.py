@@ -20,14 +20,15 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from harness import costs, readers, scopes
+from harness import readers, runner, scopes
 from harness import trace as trace_lib
 from harness.common import Run
-from harness.weights import make_weights
 
 import tiny
 
 DATA = pathlib.Path(__file__).parent / "data"
+dense = runner.load_family(pathlib.Path(__file__).resolve().parents[2],
+                           "dense")
 US = 1000
 FWD = "jit(tick)/while/body/closed_call/jvp()/while/body/closed_call"
 BWD = ("jit(tick)/while/body/closed_call/transpose(jvp())/while/body/"
@@ -127,15 +128,15 @@ def test_no_program_span_gives_nothing():
 
 def test_param_count_matches_the_weights():
     m = tiny.train_model()
-    w = make_weights(m, 3, "float32")
-    assert scopes.param_count(m) == sum(
+    w = dense.make_weights(m, 3, "float32")
+    assert dense.param_count(m) == sum(
         int(np.prod(x.shape)) for x in jax.tree.leaves(w))
 
 
 def _run(tr, names, model, steps=4, seq=32):
     run = Run(cell={}, model=model, mix={"batch": 1, "seq_len": seq},
               seed=1, window_s=1.0, setup_s=1.0, compiles_in_window=0,
-              memory_peak=0, kernels={"jit_tick": {}})
+              memory_peak=0, kernels={"jit_tick": {}}, family=dense)
     run.trace = tr
     run.peaks = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11}
     run.train = {"traced_steps": steps, "traced_tokens": steps * seq}
@@ -146,13 +147,13 @@ def _run(tr, names, model, steps=4, seq=32):
 def test_readers_by_hand(tr, names):
     m = tiny.train_model()
     run = _run(tr, names, m)
-    f = costs.train_flops_per_token(m, 32) * 4 * 32
+    f = dense.train_flops_per_token(m, 32) * 4 * 32
     assert scopes.train_phase_mfu(run, "forward") == pytest.approx(
         100 * f / 3 / (14e-6 * 1e12))
     assert scopes.train_phase_mfu(run, "backward") == pytest.approx(
         100 * f * 2 / 3 / (6e-6 * 1e12))
     assert scopes.adamw_hbm_share(run) == pytest.approx(
-        100 * 28 * scopes.param_count(m) * 4 / (13.5e-6 * 1e11))
+        100 * 28 * dense.param_count(m) * 4 / (13.5e-6 * 1e11))
     assert scopes.span_idle_share(run, "train.readback") == pytest.approx(
         100 * 2 / 40)
     assert scopes.span_idle_share(run, "serve.readback") == 0.0
@@ -176,7 +177,7 @@ def test_full_coverage_recombines_to_mfu_train(names):
     tr = trace_lib.Trace(0, 30 * US, ops, [(0, 26 * US, "jit_tick(1)")], [])
     m = tiny.train_model()
     run = _run(tr, names, m)
-    f_tok = costs.train_flops_per_token(m, 32) * run.train["traced_tokens"]
+    f_tok = dense.train_flops_per_token(m, 32) * run.train["traced_tokens"]
     t_opt = 6e-6
     fwd = scopes.train_phase_mfu(run, "forward")
     bwd = scopes.train_phase_mfu(run, "backward")

@@ -99,6 +99,7 @@ def test_new_mix_cell_and_metric_need_only_new_files(tmp_path):
     (tmp_path / "bench" / "traffic").mkdir(parents=True)
     (tmp_path / "bench" / "configs").mkdir()
     shutil.copytree(BENCH / "metrics", tmp_path / "bench" / "metrics")
+    shutil.copytree(BENCH / "families", tmp_path / "bench" / "families")
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     model = tiny.serve_model()
     (tmp_path / "bench" / "configs" / "tiny.json").write_text(
